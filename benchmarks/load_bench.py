@@ -174,6 +174,8 @@ def main(argv=None):
 
     import jax
     from repro import obs
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     requests = args.requests or (16 if args.fast else 64)
     trace = obs.generate(args.dist, requests=requests, seed=args.seed,
@@ -236,9 +238,10 @@ def main(argv=None):
               f"{a['dispatches']} dispatches  "
               f"{a['modeled_bytes_measured']:,} B")
 
+    from repro.core.troop import pallas_interpret
     payload = {
         "backend": jax.default_backend(),
-        "interpret_mode": True,
+        "interpret_mode": pallas_interpret(),
         "workload": trace.meta,
         "rows": rows,
         "energy": energy,

@@ -368,6 +368,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     iters = 1 if args.fast else 3
     N, K = (256, 1024) if args.fast else (2048, 4096)
     S, page = (128, 32) if args.fast else (1024, 32)
@@ -410,9 +412,10 @@ def main(argv=None):
               f"{a['dispatches']} dispatches  "
               f"{a['modeled_bytes_measured']:,} B")
 
+    from repro.core.troop import pallas_interpret
     payload = {
         "backend": jax.default_backend(),
-        "interpret_mode": True,
+        "interpret_mode": pallas_interpret(),
         "int8_rtol": INT8_RTOL, "int4_rtol": INT4_RTOL,
         "mx4_bytes_ratio": MX4_BYTES_RATIO,
         "fp8_bytes_ratio": FP8_BYTES_RATIO,

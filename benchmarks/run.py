@@ -10,6 +10,8 @@ import sys
 
 def main() -> None:
     quick = "--quick" in sys.argv
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig5_utilization, fig7_roofline,
                             table1_footprint, table2_energy)
     print("name,value,derived")

@@ -255,13 +255,18 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
 
-    # The tp rows simulate a 4-way mesh on the host; the flag must land
-    # before the first jax import in this process.
-    if "--xla_force_host_platform_device_count" not in \
+    # Under JAX_PLATFORMS=cpu the tp rows simulate a 4-way mesh on the host;
+    # the flag must land before the first jax import in this process.
+    # Anywhere else the tp rows need four real devices.
+    if os.environ.get("JAX_PLATFORMS") == "cpu" and \
+            "--xla_force_host_platform_device_count" not in \
             os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    " --xla_force_host_platform_device_count=4")
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     requests = args.requests or (6 if args.fast else 12)
     max_new = args.max_new or (6 if args.fast else 16)
     iters = 1 if args.fast else 3
@@ -357,9 +362,10 @@ def main(argv=None):
               f"roof {r['roofline_us']:>8.3f} us  "
               f"frac {r['fraction_of_roofline']:.3e}")
 
+    from repro.core.troop import pallas_interpret
     payload = {
         "backend": jax.default_backend(),
-        "interpret_mode": True,
+        "interpret_mode": pallas_interpret(),
         "engines": engines,
         "decode_kernels": kernels,
     }

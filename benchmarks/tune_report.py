@@ -53,6 +53,8 @@ def main(argv=None):
     from repro.core.roofline import PEAK_FLOPS
     from repro.tune.search import roofline_bw
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     keep = args.keep if args.keep is not None else (2 if args.fast else 4)
     iters = args.iters if args.iters is not None else (1 if args.fast else 3)
@@ -104,9 +106,10 @@ def main(argv=None):
               f"{r['measured_us']:>10.1f}{r['roofline_us']:>10.3f}"
               f"{str(r['from_cache']):>8}")
 
+    from repro.core.troop import pallas_interpret
     payload = {
         "backend": jax.default_backend(),
-        "interpret_mode": True,
+        "interpret_mode": pallas_interpret(),
         "roofline_bytes_per_s": roofline_bw(),
         "peak_flops": PEAK_FLOPS,
         "cache_path": cache.path,

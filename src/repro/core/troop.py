@@ -30,7 +30,6 @@ class TroopConfig:
     block_n: int = 256        # output-tile rows
     block_k: int = 512        # reduction-tile depth
     scrambled_layout: bool = True   # pre-tiled weights (E)
-    interpret: bool = True    # CPU validation mode (TPU: False)
 
     def validate(self):
         assert self.streams in (1, 2), "paper evaluates 1 or 2 interfaces"
@@ -42,10 +41,38 @@ BASELINE = TroopConfig(streams=1, unroll=1, scrambled_layout=False)
 TROOP = TroopConfig(streams=2, unroll=2, scrambled_layout=True)
 
 
+def pallas_interpret() -> bool:
+    """Whether ``pallas_call`` runs in the interpreter, read from the default
+    backend at trace time: interpreted on ``cpu`` (tests), compiled to a
+    Mosaic kernel on ``tpu``.  Any other backend raises — a kernel never
+    falls back to the interpreter or to its jnp oracle on an accelerator."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run on 'cpu' (interpreted) or 'tpu' "
+                       f"(compiled); the default backend is {backend!r}")
+
+
 def sublane(dtype) -> int:
     """Minor-to-major second dim granule for a dtype on TPU."""
     import jax.numpy as jnp
     return {2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+
+
+def block_rows(rows: int, want: int, granule: int) -> int:
+    """Rows per block of a (rows, 128) stream: the largest multiple of the
+    sublane ``granule`` that divides ``rows`` and is at most
+    ``max(want, granule)`` — or ``rows`` itself (one whole-array block) when
+    no multiple of the granule divides it."""
+    br = max(want // granule, 1) * granule
+    while br >= granule:
+        if rows % br == 0:
+            return br
+        br -= granule
+    return rows
 
 
 def aligned(dim: int, dtype, lane: bool = False) -> bool:

@@ -22,6 +22,9 @@ def bubble_fraction(stages: int, microbatches: int) -> float:
 def make_pipeline_fn(stage_fn, mesh=None, num_microbatches: int = 8):
     """stage_fn(params_s, x_mb) -> x_mb; stage params stacked on axis 0."""
     M = num_microbatches
+    if mesh is not None:
+        from repro.launch.mesh import auto_axes
+        mesh = auto_axes(mesh)     # the stage constraint needs Auto axes
 
     def pipe(stage_params, x):
         S = jax.tree.leaves(stage_params)[0].shape[0]

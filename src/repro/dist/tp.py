@@ -29,7 +29,11 @@ Two execution modes, selected per engine:
     heads, and the head/ffn shards are re-concatenated with a tiled
     ``all_gather`` before the (replicated) output projections.  Every
     device then holds bitwise-identical logits, which is what makes the
-    TP engine *token-identical* to the single-device engine.
+    TP engine *token-identical* to the single-device engine.  On TPU the
+    engine's steps compile with XLA's excess precision off
+    (``compiler_options``): otherwise the compiler rounds bf16 chains
+    where a fusion ends, and the ``all_gather`` ends them in places the
+    single-device program does not.
   * ``"overlap"``: the row/column-parallel projections route through
     ``repro.dist.collective_matmul``'s ring collectives
     (``allgather_matmul`` for qkv/up/gate, ``reduce_scatter_matmul`` for
@@ -94,6 +98,21 @@ def enter(plan: TPPlan):
         yield
     finally:
         _STATE.plan = prev
+
+
+def compiler_options(mode: str) -> Optional[Dict[str, Any]]:
+    """XLA options for every jitted step of an engine in ``mode``, at any
+    ``tp`` (the single-device engine is what a TP engine must match).
+
+    Exact mode promises tokens that do not depend on ``tp``.  On TPU, XLA
+    by default runs a bf16 elementwise chain in f32 and rounds it only
+    where a fusion ends; a tp>1 program's ``all_gather`` ends fusions
+    where the tp=1 program does not, so the two round differently and
+    near-tied logits flip.  With excess precision off every bf16 op
+    rounds, wherever fusions end.  The CPU programs agree without it."""
+    if mode == "exact" and jax.default_backend() == "tpu":
+        return {"xla_allow_excess_precision": False}
+    return None
 
 
 # ---------------------------------------------------------------- helpers
@@ -175,7 +194,8 @@ def plan(model, tp: int, mode: str = "exact") -> TPPlan:
         if cfg.moe.num_experts % tp:
             raise ValueError(f"num_experts {cfg.moe.num_experts} not "
                              f"divisible by tp={tp}")
-    mesh = jax.make_mesh((tp,), (AXIS,))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((tp,), (AXIS,))
     return TPPlan(size=tp, kv_shards=kv_shards, mode=mode, mesh=mesh)
 
 
@@ -321,7 +341,8 @@ class TPExecutor:
             sm = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                            out_specs=out_specs, check_rep=False)
             return jax.jit(sm, donate_argnums=()
-                           if donate is None else (donate,))
+                           if donate is None else (donate,),
+                           compiler_options=compiler_options(plan_.mode))
 
         def call(*args):
             f = state.get("f")

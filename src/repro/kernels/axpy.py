@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import (TroopConfig, block_rows, pallas_interpret,
+                              sublane)
 from repro.tune.registry import itemsize, troop_kernel
 
 
@@ -56,11 +57,12 @@ def axpy(a, x, y, cfg: TroopConfig = TroopConfig()):
     a = jnp.asarray(a, jnp.float32).reshape(1)
     x2, y2 = x.reshape(-1, lanes), y.reshape(-1, lanes)
     rows = x2.shape[0]
-    br = max(min(cfg.block_k * cfg.unroll // lanes, rows // cfg.streams), 1)
+    sub = max(sublane(x.dtype), sublane(y.dtype))
+    # two half-streams need each half to tile by the sublane granule
+    streams = cfg.streams if rows % (2 * sub) == 0 else 1
+    br = block_rows(rows // streams, cfg.block_k * cfg.unroll // lanes, sub)
 
-    if cfg.streams == 1:
-        while rows % br:
-            br //= 2
+    if streams == 1:
         out = pl.pallas_call(
             _kernel_1s,
             grid=(rows // br,),
@@ -69,13 +71,11 @@ def axpy(a, x, y, cfg: TroopConfig = TroopConfig()):
                       pl.BlockSpec((br, lanes), lambda j: (j, 0))],
             out_specs=pl.BlockSpec((br, lanes), lambda j: (j, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, lanes), y.dtype),
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(a, x2, y2)
         return out.reshape(K)
 
     half = rows // 2
-    while half % br:
-        br //= 2
     steps = half // br
     out0, out1 = pl.pallas_call(
         _kernel_2s,
@@ -91,6 +91,6 @@ def axpy(a, x, y, cfg: TroopConfig = TroopConfig()):
                    pl.BlockSpec((br, lanes), lambda j: (j, 0))],
         out_shape=[jax.ShapeDtypeStruct((half, lanes), y.dtype),
                    jax.ShapeDtypeStruct((half, lanes), y.dtype)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(a, x2, x2, y2, y2)
     return jnp.concatenate([out0, out1], axis=0).reshape(K)

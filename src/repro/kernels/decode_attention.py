@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, troop_kernel
 
 _NEG = -1e30
@@ -127,7 +127,7 @@ def decode_attention_stats(q, k, v, length, cfg: TroopConfig = TroopConfig(),
             grid=(B, steps),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, lo, lo],
             out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(length, qg, k, v)
     else:
         acc, m, l = pl.pallas_call(
@@ -136,7 +136,7 @@ def decode_attention_stats(q, k, v, length, cfg: TroopConfig = TroopConfig(),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), q_spec,
                       lo, lo, hi, hi],
             out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(length, qg, k, v, k, v)
     return acc, m, l
 
@@ -250,7 +250,7 @@ def _paged_decode_attention(q, k_pool, v_pool, block_tables, length,
         out = pl.pallas_call(
             functools.partial(_kernel_paged_1s, scale=scale, page=page),
             grid_spec=grid_spec, out_shape=out_shape,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(block_tables, length, qg, k_pool, v_pool)
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -261,7 +261,7 @@ def _paged_decode_attention(q, k_pool, v_pool, block_tables, length,
             functools.partial(_kernel_paged_2s, scale=scale, page=page,
                               half=half),
             grid_spec=grid_spec, out_shape=out_shape,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(block_tables, length, qg, k_pool, v_pool, k_pool, v_pool)
     return out.reshape(B, H, hd).astype(q.dtype)
 
@@ -387,7 +387,7 @@ def decode_attention_int8(q, k8, k_scale, v8, v_scale, length,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), q_spec,
                   kv_spec, sc_spec, kv_spec, sc_spec],
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(length, qg, k8, k_scale, v8, v_scale)
     out = acc / jnp.maximum(l, 1e-30)
     return out.reshape(B, H, hd).astype(q.dtype)
@@ -467,7 +467,7 @@ def _paged_decode_attention_int8(q, k_pool, k_scales, v_pool, v_scales,
         out = pl.pallas_call(
             functools.partial(_kernel_paged_q8_1s, scale=scale, page=page),
             grid_spec=grid_spec, out_shape=out_shape,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(block_tables, length, qg, k_pool, k_scales, v_pool, v_scales)
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -478,7 +478,7 @@ def _paged_decode_attention_int8(q, k_pool, k_scales, v_pool, v_scales,
             functools.partial(_kernel_paged_q8_2s, scale=scale, page=page,
                               half=half),
             grid_spec=grid_spec, out_shape=out_shape,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(block_tables, length, qg, k_pool, k_scales, v_pool, v_scales,
           k_pool, k_scales, v_pool, v_scales)
     return out.reshape(B, H, hd).astype(q.dtype)

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import (TroopConfig, block_rows, pallas_interpret,
+                              sublane)
 from repro.tune.registry import itemsize, troop_kernel
 
 
@@ -68,17 +69,15 @@ def _kernel_2s(x0, x1, y0, y1, o_ref, acc):
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def dotp(x, y, cfg: TroopConfig = TroopConfig()):
     """x, y (K,) -> scalar fp32."""
-    K = x.shape[0]
     lanes = 128
-    bk = min(cfg.block_k * cfg.unroll, K // (cfg.streams * lanes) * lanes)
-    bk = max(bk // lanes * lanes, lanes)
     x2, y2 = x.reshape(-1, lanes), y.reshape(-1, lanes)
     rows = x2.shape[0]
-    br = max(bk // lanes, 1)
+    sub = max(sublane(x.dtype), sublane(y.dtype))
+    # two half-streams need each half to tile by the sublane granule
+    streams = cfg.streams if rows % (2 * sub) == 0 else 1
+    br = block_rows(rows // streams, cfg.block_k * cfg.unroll // lanes, sub)
 
-    if cfg.streams == 1:
-        while rows % br:
-            br //= 2
+    if streams == 1:
         out = pl.pallas_call(
             _kernel_1s,
             grid=(rows // br,),
@@ -88,14 +87,11 @@ def dotp(x, y, cfg: TroopConfig = TroopConfig()):
                                    memory_space=pltpu.SMEM),
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
             scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(x2, y2)
         return out[0, 0]
 
-    half = rows // 2
-    while half % br:
-        br //= 2
-    steps = half // br
+    steps = rows // 2 // br
     out = pl.pallas_call(
         _kernel_2s,
         grid=(steps,),
@@ -109,6 +105,6 @@ def dotp(x, y, cfg: TroopConfig = TroopConfig()):
                                memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(x2, x2, y2, y2)
     return out[0, 0]

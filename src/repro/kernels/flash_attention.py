@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, troop_kernel
 
 _NEG = -1e30
@@ -110,6 +110,6 @@ def flash_attention(q, k, v, causal: bool = True,
         scratch_shapes=[pltpu.VMEM((KV, bq, G, 1), jnp.float32),
                         pltpu.VMEM((KV, bq, G, 1), jnp.float32),
                         pltpu.VMEM((KV, bq, G, hd), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(qg, k, v)
     return out.reshape(B, T, H, hd)

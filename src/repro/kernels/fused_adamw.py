@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, numel, troop_kernel
 
 
@@ -93,7 +93,7 @@ def fused_adamw(p, g, mu, nu, *, lr, b1, b2, eps, wd, bc1, bc2,
             out_shape=[jax.ShapeDtypeStruct((rows, lanes), dtype),
                        jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
                        jax.ShapeDtypeStruct((rows, lanes), jnp.float32)],
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(h, pf, gf, muf, nuf)
         po, muo, nuo = outs
     else:
@@ -112,7 +112,7 @@ def fused_adamw(p, g, mu, nu, *, lr, b1, b2, eps, wd, bc1, bc2,
             out_specs=[lo(), lo(), lo(), lo(), lo(), lo()],
             out_shape=[sh(dtype), sh(dtype), sh(jnp.float32),
                        sh(jnp.float32), sh(jnp.float32), sh(jnp.float32)],
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(h, pf, pf, gf, gf, muf, muf, nuf, nuf)
         po = jnp.concatenate([outs[0], outs[1]])
         muo = jnp.concatenate([outs[2], outs[3]])

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, troop_kernel
 
 
@@ -85,7 +85,11 @@ def _kernel_2s(w0_ref, w1_ref, x0_ref, x1_ref, o_ref, acc):
     ref="gemv", example=_example)
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def gemv(w, x, cfg: TroopConfig = TroopConfig()):
-    """w (N,K), x (K,) -> y (N,) fp32."""
+    """w (N,K), x (K,) -> y (N,) fp32.
+
+    When ``block_n`` does not divide N the last row tile is ragged: its
+    out-of-range rows read padding and their results are never written, so
+    every one of the N outputs (e.g. a 151936-row vocabulary) comes out."""
     N, K = w.shape
     bn = min(cfg.block_n, N)
     bk = min(cfg.block_k * cfg.unroll, K)
@@ -94,7 +98,7 @@ def gemv(w, x, cfg: TroopConfig = TroopConfig()):
     if cfg.streams == 1:
         while K % bk:
             bk //= 2
-        grid = (N // bn, K // bk)
+        grid = (pl.cdiv(N, bn), K // bk)
         return pl.pallas_call(
             _kernel_1s,
             grid=grid,
@@ -105,7 +109,7 @@ def gemv(w, x, cfg: TroopConfig = TroopConfig()):
             out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
             scratch_shapes=[_scratch(bn)],
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(w, x2).reshape(N)
 
     # streams == 2: stream0 = first K half, stream1 = second K half
@@ -113,7 +117,7 @@ def gemv(w, x, cfg: TroopConfig = TroopConfig()):
     while Kh % bk:
         bk //= 2
     steps = Kh // bk
-    grid = (N // bn, steps)
+    grid = (pl.cdiv(N, bn), steps)
     off = steps  # block offset of the second half
 
     return pl.pallas_call(
@@ -128,7 +132,7 @@ def gemv(w, x, cfg: TroopConfig = TroopConfig()):
         out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
         scratch_shapes=[_scratch(bn)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(w, w, x2, x2).reshape(N)
 
 

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, numel, troop_kernel
 
 
@@ -129,7 +129,7 @@ def mamba_scan(x, dt, B, C, A, D, state0, cfg: TroopConfig = TroopConfig()):
         out_shape=[jax.ShapeDtypeStruct((b * nch, T, dc), jnp.float32),
                    jax.ShapeDtypeStruct((b * nch, dc, ds), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dc, ds), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(xf, dtf, Bf, Cf, Af.reshape(nch, dc, ds), Df)
 
     y = (y.reshape(b, nch, T, dc).transpose(0, 2, 1, 3).reshape(b, T, di))

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, troop_kernel
 
 _NEG = -1e30
@@ -144,7 +144,7 @@ def _prefill_attention_paged(q, k_pool, v_pool, block_tables, q_offset,
         out = pl.pallas_call(
             functools.partial(_kernel_1s, scale=scale, page=page),
             grid_spec=grid_spec, out_shape=out_shape,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(block_tables, q_offset, length, qg, k_pool, v_pool)
     else:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -154,7 +154,7 @@ def _prefill_attention_paged(q, k_pool, v_pool, block_tables, q_offset,
         out = pl.pallas_call(
             functools.partial(_kernel_2s, scale=scale, page=page, half=half),
             grid_spec=grid_spec, out_shape=out_shape,
-            interpret=cfg.interpret,
+            interpret=pallas_interpret(),
         )(block_tables, q_offset, length, qg, k_pool, v_pool, k_pool, v_pool)
     return out.reshape(B, C, H, hd)
 

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, troop_kernel
 
 
@@ -51,5 +51,5 @@ def rmsnorm(x, scale, eps: float = 1e-6, cfg: TroopConfig = TroopConfig()):
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bt, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((T, d), x.dtype),
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(x, s2)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.tune.registry import itemsize, numel, troop_kernel
 
 
@@ -135,7 +135,7 @@ def wkv6(r, k, v, w, u, state0, cfg: TroopConfig = TroopConfig()):
         out_shape=[jax.ShapeDtypeStruct((B * H, T, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B * H, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(rf, kf, vf, wf, u)
     y = jnp.moveaxis(y.reshape(B, H, T, hd), 1, 2)
     return y, state.reshape(B, H, hd, hd)

@@ -251,6 +251,7 @@ def main():
     else:
         todo.append((args.arch, args.shape, args.mesh))
 
+    failed = 0
     for arch, sname, mesh_kind in todo:
         path = os.path.join(args.out, f"{arch}__{sname}__{mesh_kind}.json")
         if args.all and os.path.exists(path):
@@ -264,7 +265,10 @@ def main():
         print(f"[{rec['status']:5s}] {arch} {sname} {mesh_kind} "
               f"({rec['wall_s']:.0f}s)", flush=True)
         if rec["status"] == "error":
+            failed += 1
             print(rec["error"], flush=True)
+    if failed:
+        raise SystemExit(f"{failed} of {len(todo)} cells failed")
 
 
 if __name__ == "__main__":

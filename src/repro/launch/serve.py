@@ -7,9 +7,10 @@ page-pool KV with block tables, see serve.kvcache).  ``--smoke`` uses the
 reduced config on the host and prints the engine metrics.
 
 ``--tp N`` serves tensor-parallel over N devices (``repro.dist.tp``,
-DESIGN.md §8); on a CPU host the launcher simulates the mesh by setting
-``XLA_FLAGS=--xla_force_host_platform_device_count`` *before* JAX loads —
-which is why every heavyweight import lives inside ``main``.
+DESIGN.md §8).  Only under ``JAX_PLATFORMS=cpu`` does the launcher simulate
+the mesh, by setting ``XLA_FLAGS=--xla_force_host_platform_device_count``
+*before* JAX loads — which is why every heavyweight import lives inside
+``main``.  Anywhere else ``--tp N`` needs N real devices.
 """
 import argparse
 import json
@@ -34,7 +35,8 @@ def main():
     ap.add_argument("--backend", choices=("dense", "paged"), default="paged")
     ap.add_argument("--kernel-decode", action="store_true",
                     help="attend via the tuned Pallas paged kernel (no "
-                         "gathered dense view; slow in CPU interpret mode)")
+                         "gathered dense view; interpreted, and slow, on "
+                         "the CPU)")
     ap.add_argument("--chunked-prefill", action="store_true",
                     help="prefill as fixed-size token slabs interleaved "
                          "with decode (one compiled prefill shape, no "
@@ -100,7 +102,8 @@ def main():
                          "rows + audit result) as JSON; implies --profile")
     args = ap.parse_args()
 
-    if args.tp > 1 and "--xla_force_host_platform_device_count" not in \
+    if args.tp > 1 and os.environ.get("JAX_PLATFORMS") == "cpu" and \
+            "--xla_force_host_platform_device_count" not in \
             os.environ.get("XLA_FLAGS", ""):
         # simulate the mesh on CPU: must land before jax is imported
         os.environ["XLA_FLAGS"] = (
@@ -109,6 +112,9 @@ def main():
 
     import jax
     import numpy as np
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config, reduced
     from repro.models import RuntimeConfig, build_model

@@ -13,6 +13,7 @@ import jax
 from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig
 from repro.launch import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import data_shards, make_host_mesh, make_production_mesh
 from repro.models import RuntimeConfig, build_model
 from repro.optim import OptConfig
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

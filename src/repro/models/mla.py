@@ -138,8 +138,12 @@ def apply_mla_decode(p, cfg, x, cache: MLACache, pos, dtype, mode="absorb"):
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
 
     if mode == "absorb":
-        out_lat = jnp.einsum("bhts,bsc->bthc", probs, c_kv,
-                             preferred_element_type=jnp.float32)
+        # contract to (b, h, t, c) and move the head axis after: the CPU
+        # runtime has no bf16 x bf16 -> f32 kernel for the (b, t, h, c)
+        # output layout of this dot
+        out_lat = jnp.swapaxes(
+            jnp.einsum("bhts,bsc->bhtc", probs, c_kv,
+                       preferred_element_type=jnp.float32), 1, 2)
         wuv = p["wuv"]["w"].astype(dtype).reshape(
             m.kv_lora_rank, H, m.v_head_dim)
         out = jnp.einsum("bthc,chi->bthi", out_lat.astype(dtype), wuv,

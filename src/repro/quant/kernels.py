@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.troop import TroopConfig
+from repro.core.troop import TroopConfig, pallas_interpret
 from repro.quant.tensor import E8M0_BIAS, quantize, quantize_mx
 from repro.tune.registry import itemsize, numel, troop_kernel
 
@@ -127,7 +127,7 @@ def _qgemv_2d(wq, scales, x2, cfg: TroopConfig, bits: int):
         out_specs=pl.BlockSpec((bn, B), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, B), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, B), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(*ops)
 
 
@@ -315,7 +315,7 @@ def _mx_gemv_2d(wq, scales, x2, cfg: TroopConfig):
         out_specs=pl.BlockSpec((B, bn), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((B, bn), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(*ops)
 
 
@@ -436,7 +436,7 @@ def mx_qgemv_swiglu(wg, sg, wu, su, x, cfg: TroopConfig = TroopConfig()):
     pack = 2 if bits == 4 else 1
     g = K // sg.shape[0]
     one = TroopConfig(streams=1, unroll=cfg.unroll, block_n=cfg.block_n,
-                      block_k=cfg.block_k, interpret=cfg.interpret)
+                      block_k=cfg.block_k)
     bn, bk, steps, _ = _mx_tiles(N, K, g, pack, one)
     body = functools.partial(_mx_swiglu_kernel, bits=bits, g=g)
     w_spec = pl.BlockSpec((bk // pack, bn), lambda i, j: (j, i))
@@ -450,7 +450,7 @@ def mx_qgemv_swiglu(wg, sg, wu, su, x, cfg: TroopConfig = TroopConfig()):
         out_shape=jax.ShapeDtypeStruct((1, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32),
                         pltpu.VMEM((1, bn), jnp.float32)],
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(wg, sg, wu, su, x.reshape(1, -1))
     return out.reshape(-1)
 
@@ -524,7 +524,7 @@ def grouped_expert_qgemv(wq, scales, xs, expert_ids,
     pack = 2 if bits == 4 else 1
     g = K // scales.shape[1]
     one = TroopConfig(streams=1, unroll=cfg.unroll, block_n=cfg.block_n,
-                      block_k=cfg.block_k, interpret=cfg.interpret)
+                      block_k=cfg.block_k)
     bn, bk, steps, _ = _mx_tiles(N, K, g, pack, one)
     body = functools.partial(_grouped_kernel, bits=bits, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -543,5 +543,5 @@ def grouped_expert_qgemv(wq, scales, xs, expert_ids,
     return pl.pallas_call(
         body, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((topk, N), jnp.float32),
-        interpret=cfg.interpret,
+        interpret=pallas_interpret(),
     )(expert_ids.astype(jnp.int32), wq, scales, xs)

@@ -255,6 +255,10 @@ class ServingEngine:
             self.params = self._tpx.shard_params(model, params)
 
         self._prefill_traces = 0
+        # exact mode: tokens must not depend on tp, so the tp=1 steps
+        # compile with the same XLA options as the sharded ones
+        from repro.dist.tp import compiler_options
+        opts = compiler_options(config.tp_mode)
 
         def counted_prefill(params, batch):
             self._prefill_traces += 1      # runs at trace time only
@@ -267,8 +271,10 @@ class ServingEngine:
                                                    probe=prefill_step)
             self.serve_step = self._tpx.jit_step(serve_step, donate=2)
         else:
-            self.prefill_step = jax.jit(counted_prefill)
-            self.serve_step = jax.jit(serve_step, donate_argnums=(2,))
+            self.prefill_step = jax.jit(counted_prefill,
+                                        compiler_options=opts)
+            self.serve_step = jax.jit(serve_step, donate_argnums=(2,),
+                                      compiler_options=opts)
         if self.chunked:
             if chunk_step is None:
                 from repro.serve.step import make_chunk_step
@@ -283,7 +289,8 @@ class ServingEngine:
                                                      probe=chunk_step,
                                                      donate=2)
             else:
-                self.chunk_step = jax.jit(counted_chunk, donate_argnums=(2,))
+                self.chunk_step = jax.jit(counted_chunk, donate_argnums=(2,),
+                                          compiler_options=opts)
             self._copy_page = jax.jit(copy_page, donate_argnums=(0,))
         self.caches = self.backend.init_caches(model, slots, cache_len)
         if self._tpx is not None:
@@ -331,9 +338,11 @@ class ServingEngine:
             self.draft_caches = self._draft_backend.init_caches(
                 draft_model, slots, self._draft_cache_len)
             self.draft_step = jax.jit(make_draft_step(draft_model),
-                                      donate_argnums=(2,))
+                                      donate_argnums=(2,),
+                                      compiler_options=opts)
             self.verify_step = jax.jit(make_verify_step(model),
-                                       donate_argnums=(2,))
+                                       donate_argnums=(2,),
+                                       compiler_options=opts)
             self._spec_rng = np.random.default_rng(config.seed)
             self._draft_pos = np.zeros((slots,), np.int32)
             # tokens emitted by the target that the draft model has not

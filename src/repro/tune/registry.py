@@ -118,7 +118,7 @@ class KernelSpec:
 
     def heuristic(self, *args) -> TroopConfig:
         """Untuned fallback: the spec default (the repo's TROOP preset
-        semantics — streams=2, hardware-granule blocks, interpret on CPU)."""
+        semantics — streams=2, hardware-granule blocks)."""
         return self.default
 
     def key(self, *args, backend: Optional[str] = None,

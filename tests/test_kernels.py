@@ -259,3 +259,47 @@ def test_decode_attention_int8(B, H, KV, hd, S):
     full = R.decode_attention(q, k, v, length)
     np.testing.assert_allclose(np.asarray(got), np.asarray(full),
                                rtol=0.1, atol=0.05)
+
+
+def test_gemv_ragged_last_row_tile():
+    """N % block_n != 0 (a 151936-row vocabulary at block_n=256 leaves 128
+    rows): the ragged last tile writes every row."""
+    w = jax.random.normal(jax.random.PRNGKey(0), (384, 512), jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(1), (512,), jnp.bfloat16)
+    want = np.asarray(R.gemv(w, x))
+    for cfg in CFGS:
+        got = np.asarray(K.gemv(w, x, cfg))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, **tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("block_k,unroll", [(512, 1), (256, 2), (256, 1)])
+@pytest.mark.parametrize("streams", [1, 2])
+def test_dotp_axpy_sub_granule_block(block_k, unroll, streams):
+    """Configs whose block was fewer rows than the bf16 sublane granule of
+    16 (4 rows at the default block_k=512, unroll=1) round up to it."""
+    cfg = TroopConfig(streams=streams, unroll=unroll, block_k=block_k)
+    x = jax.random.normal(jax.random.PRNGKey(0), (8192,), jnp.bfloat16)
+    y = jax.random.normal(jax.random.PRNGKey(1), (8192,), jnp.bfloat16)
+    np.testing.assert_allclose(K.dotp(x, y, cfg), R.dotp(x, y), rtol=5e-2)
+    np.testing.assert_allclose(
+        np.asarray(K.axpy(1.7, x, y, cfg), np.float32),
+        np.asarray(R.axpy(1.7, x, y), np.float32), **tol(jnp.bfloat16))
+
+
+def test_block_rows_granule():
+    from repro.core.troop import block_rows
+    assert block_rows(1 << 16, 4, 16) == 16       # rounded up to the granule
+    assert block_rows(1 << 16, 40, 16) == 32      # down to a dividing multiple
+    assert block_rows(48, 64, 16) == 48
+    assert block_rows(40, 64, 16) == 40           # no multiple divides: whole
+
+
+def test_pallas_interpret_follows_backend(monkeypatch):
+    from repro.core.troop import pallas_interpret
+    assert pallas_interpret() is True              # tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        pallas_interpret()

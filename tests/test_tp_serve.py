@@ -262,3 +262,14 @@ def test_quantize_tp_alignment():
         quantize_params(params, bits=8, group_size=64, tp=4)
     q = quantize_params(params, bits=8, group_size=32, tp=4)
     assert q is not None
+
+
+def test_exact_mode_compiler_options_follow_backend(monkeypatch):
+    """Exact mode turns XLA's excess precision off on TPU only, for every
+    engine in that mode (tp=1 included); the CPU programs agree without."""
+    import jax
+    from repro.dist.tp import compiler_options
+    assert compiler_options("exact") is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert compiler_options("exact") == {"xla_allow_excess_precision": False}
+    assert compiler_options("overlap") is None
