@@ -2,6 +2,13 @@
 
 Pure-jnp reference path — GSPMD-shardable, used by the multi-pod dry-run and
 as the oracle for the Pallas flash/decode kernels.
+
+Every attention entry the model calls (``apply_attention``,
+``apply_attention_decode``, ``apply_attention_chunk_paged``, cross
+attention) runs under the ``jax.named_scope`` ``attention``, its steps under
+``qkv``, ``kv_write``, ``kv_gather``, ``attend`` and ``out_proj``: the
+scopes reach each device op's metadata, so a profile splits a step's device
+time by them.
 """
 from __future__ import annotations
 
@@ -54,6 +61,7 @@ def attention_init(key, cfg, *, cross: bool = False):
     return p
 
 
+@jax.named_scope("attend")
 def attend(q, k, v, *, causal: bool, q_offset=0, length: Optional[jax.Array] = None,
            decode: bool = False):
     """q: (B,T,H,hd) k/v: (B,S,KV,hd). GQA via head grouping. fp32 softmax.
@@ -165,6 +173,7 @@ def _tp_attend_kv(k, v, cfg):
     return k, v
 
 
+@jax.named_scope("qkv")
 def _project_qkv(p, cfg, x, x_kv, positions, kv_positions, dtype):
     B, T = x.shape[:2]
     hd = cfg.head_dim
@@ -185,6 +194,14 @@ def _project_qkv(p, cfg, x, x_kv, positions, kv_positions, dtype):
     return q, k, v
 
 
+@jax.named_scope("out_proj")
+def _out_proj(p, out, dtype):
+    """Merge the heads of ``out`` (B, T, heads*hd) and project back to
+    the model width."""
+    return M.apply_dense(p["wo"], _tp_merge_heads(out), dtype, tp="row")
+
+
+@jax.named_scope("attention")
 def apply_attention(p, cfg, x, *, positions, dtype, causal=True,
                     return_kv=False):
     """Full-sequence (train / prefill) self-attention."""
@@ -192,8 +209,7 @@ def apply_attention(p, cfg, x, *, positions, dtype, causal=True,
     ka, va = _tp_attend_kv(k, v, cfg)
     out = attend(q, ka, va, causal=causal)
     B, T = x.shape[:2]
-    out = _tp_merge_heads(out.reshape(B, T, -1))
-    out = M.apply_dense(p["wo"], out, dtype, tp="row")
+    out = _out_proj(p, out.reshape(B, T, -1), dtype)
     # §Perf B3: reduce the TP partial sum HERE, in bf16 — otherwise XLA
     # defers the all-reduce past the next norm's fp32 upcast (2x bytes).
     # §Perf B4: name the post-psum tensor so the remat policy can SAVE it —
@@ -292,6 +308,7 @@ def update_paged_cache_chunk(pool, new, offset, valid, block_tables):
         rows, mode="drop")
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_kv(cache: PagedKVCache, block_tables,
                     dtype=jnp.bfloat16):
     """Dense logical view of a paged cache: (B, nblk*page, KV, hd).
@@ -328,40 +345,44 @@ def apply_attention_decode_paged(p, cfg, x, cache: PagedKVCache, pos,
     B = x.shape[0]
     q, k_new, v_new = _project_qkv(
         p, cfg, x, x, pos[:, None], pos[:, None], dtype)
-    if cache.quantized:
-        # int8 pools: quantize the new token row and write value + scale
-        # pages through the same table entry (§Perf A4 at page granularity)
-        k8, ks = quantize_kv(k_new)
-        v8, vs = quantize_kv(v_new)
-        new_cache = PagedKVCache(
-            update_paged_cache(cache.k_pool, k8, pos, block_tables),
-            update_paged_cache(cache.v_pool, v8, pos, block_tables),
-            update_paged_cache(cache.k_scale_pool, ks, pos, block_tables),
-            update_paged_cache(cache.v_scale_pool, vs, pos, block_tables))
-    else:
-        new_cache = PagedKVCache(
-            update_paged_cache(cache.k_pool, k_new, pos, block_tables),
-            update_paged_cache(cache.v_pool, v_new, pos, block_tables))
+    with jax.named_scope("kv_write"):
+        if cache.quantized:
+            # int8 pools: quantize the new token row and write value +
+            # scale pages through the same table entry (§Perf A4 at page
+            # granularity)
+            k8, ks = quantize_kv(k_new)
+            v8, vs = quantize_kv(v_new)
+            new_cache = PagedKVCache(
+                update_paged_cache(cache.k_pool, k8, pos, block_tables),
+                update_paged_cache(cache.v_pool, v8, pos, block_tables),
+                update_paged_cache(cache.k_scale_pool, ks, pos,
+                                   block_tables),
+                update_paged_cache(cache.v_scale_pool, vs, pos,
+                                   block_tables))
+        else:
+            new_cache = PagedKVCache(
+                update_paged_cache(cache.k_pool, k_new, pos, block_tables),
+                update_paged_cache(cache.v_pool, v_new, pos, block_tables))
     if use_kernel:
         from repro.kernels import ops as KO   # lazy: keeps models jnp-only
-        if cache.quantized:
-            out = KO.paged_decode_attention_int8(   # dispatches via tune
-                q[:, 0], new_cache.k_pool, new_cache.k_scale_pool,
-                new_cache.v_pool, new_cache.v_scale_pool, block_tables,
-                pos + 1)[:, None]
-        else:
-            out = KO.paged_decode_attention(        # dispatches via tune
-                q[:, 0], new_cache.k_pool, new_cache.v_pool, block_tables,
-                pos + 1)[:, None]
+        with jax.named_scope("attend"):
+            if cache.quantized:
+                out = KO.paged_decode_attention_int8(  # dispatches via tune
+                    q[:, 0], new_cache.k_pool, new_cache.k_scale_pool,
+                    new_cache.v_pool, new_cache.v_scale_pool, block_tables,
+                    pos + 1)[:, None]
+            else:
+                out = KO.paged_decode_attention(       # dispatches via tune
+                    q[:, 0], new_cache.k_pool, new_cache.v_pool,
+                    block_tables, pos + 1)[:, None]
     else:
         k, v = gather_paged_kv(new_cache, block_tables, dtype)
         k, v = _tp_attend_kv(k, v, cfg)
         out = attend(q, k, v, causal=False, length=pos + 1, decode=True)
-    out = _tp_merge_heads(out.reshape(B, 1, -1))
-    out = M.apply_dense(p["wo"], out, dtype, tp="row")
-    return out, new_cache
+    return _out_proj(p, out.reshape(B, 1, -1), dtype), new_cache
 
 
+@jax.named_scope("attention")
 def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
                                 valid, stage_base, dtype, block_tables,
                                 stage: Optional[ChunkStage] = None,
@@ -388,31 +409,33 @@ def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
     B, C = x.shape[:2]
     positions = offset[:, None] + jnp.arange(C)[None, :]
     q, k_new, v_new = _project_qkv(p, cfg, x, x, positions, positions, dtype)
-    if cache.quantized:
-        k8, ks = quantize_kv(k_new)
-        v8, vs = quantize_kv(v_new)
-        new_cache = PagedKVCache(
-            update_paged_cache_chunk(cache.k_pool, k8, offset, valid,
-                                     block_tables),
-            update_paged_cache_chunk(cache.v_pool, v8, offset, valid,
-                                     block_tables),
-            update_paged_cache_chunk(cache.k_scale_pool, ks, offset, valid,
-                                     block_tables),
-            update_paged_cache_chunk(cache.v_scale_pool, vs, offset, valid,
-                                     block_tables))
-    else:
-        new_cache = PagedKVCache(
-            update_paged_cache_chunk(cache.k_pool, k_new, offset, valid,
-                                     block_tables),
-            update_paged_cache_chunk(cache.v_pool, v_new, offset, valid,
-                                     block_tables))
+    with jax.named_scope("kv_write"):
+        if cache.quantized:
+            k8, ks = quantize_kv(k_new)
+            v8, vs = quantize_kv(v_new)
+            new_cache = PagedKVCache(
+                update_paged_cache_chunk(cache.k_pool, k8, offset, valid,
+                                         block_tables),
+                update_paged_cache_chunk(cache.v_pool, v8, offset, valid,
+                                         block_tables),
+                update_paged_cache_chunk(cache.k_scale_pool, ks, offset,
+                                         valid, block_tables),
+                update_paged_cache_chunk(cache.v_scale_pool, vs, offset,
+                                         valid, block_tables))
+        else:
+            new_cache = PagedKVCache(
+                update_paged_cache_chunk(cache.k_pool, k_new, offset, valid,
+                                         block_tables),
+                update_paged_cache_chunk(cache.v_pool, v_new, offset, valid,
+                                         block_tables))
     length = offset + valid
     new_stage = None
     if use_kernel and not cache.quantized:
         from repro.kernels import ops as KO   # lazy: keeps models jnp-only
-        out = KO.prefill_attention_paged(
-            q, new_cache.k_pool, new_cache.v_pool, block_tables, offset,
-            length)
+        with jax.named_scope("attend"):
+            out = KO.prefill_attention_paged(
+                q, new_cache.k_pool, new_cache.v_pool, block_tables, offset,
+                length)
     else:
         k, v = gather_paged_kv(new_cache, block_tables, dtype)
         if stage is not None:
@@ -435,13 +458,13 @@ def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
         out = attend(q, k, v, causal=True,
                      q_offset=offset[:, None, None, None, None],
                      length=length)
-    out = _tp_merge_heads(out.reshape(B, C, -1))
-    out = M.apply_dense(p["wo"], out, dtype, tp="row")
+    out = _out_proj(p, out.reshape(B, C, -1), dtype)
     if stage is not None and new_stage is None:   # kernel path keeps stage
         new_stage = stage
     return out, new_cache, new_stage
 
 
+@jax.named_scope("attention")
 def apply_attention_decode(p, cfg, x, cache, pos, dtype, block_tables=None,
                            use_kernel=False):
     """Single-token decode. ``pos``: (B,) current position; cache has fixed S."""
@@ -452,36 +475,43 @@ def apply_attention_decode(p, cfg, x, cache, pos, dtype, block_tables=None,
     q, k_new, v_new = _project_qkv(
         p, cfg, x, x, pos[:, None], pos[:, None], dtype)
     cs = ("batch", "cache_seq", None, None)
+    with jax.named_scope("kv_write"):
+        if cache.quantized:
+            kq, ks = quantize_kv(k_new)
+            vq, vs = quantize_kv(v_new)
+            new_cache = KVCache(
+                PT.constrain(update_cache(cache.k, kq, pos), cs),
+                PT.constrain(update_cache(cache.v, vq, pos), cs),
+                update_cache(cache.k_scale, ks, pos),
+                update_cache(cache.v_scale, vs, pos))
+        else:
+            new_cache = KVCache(
+                PT.constrain(update_cache(cache.k, k_new, pos), cs),
+                PT.constrain(update_cache(cache.v, v_new, pos), cs))
     if cache.quantized:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        new_cache = KVCache(
-            PT.constrain(update_cache(cache.k, kq, pos), cs),
-            PT.constrain(update_cache(cache.v, vq, pos), cs),
-            update_cache(cache.k_scale, ks, pos),
-            update_cache(cache.v_scale, vs, pos))
-        k = dequantize_kv(new_cache.k, new_cache.k_scale, dtype)
-        v = dequantize_kv(new_cache.v, new_cache.v_scale, dtype)
+        with jax.named_scope("kv_gather"):
+            k = dequantize_kv(new_cache.k, new_cache.k_scale, dtype)
+            v = dequantize_kv(new_cache.v, new_cache.v_scale, dtype)
     else:
-        k = PT.constrain(update_cache(cache.k, k_new, pos), cs)
-        v = PT.constrain(update_cache(cache.v, v_new, pos), cs)
-        new_cache = KVCache(k, v)
+        k, v = new_cache.k, new_cache.v
     k, v = _tp_attend_kv(k, v, cfg)
     out = attend(q, k, v, causal=False, length=pos + 1, decode=True)
-    out = _tp_merge_heads(out.reshape(B, 1, -1))
-    out = M.apply_dense(p["wo"], out, dtype, tp="row")
-    return out, new_cache
+    return _out_proj(p, out.reshape(B, 1, -1), dtype), new_cache
 
 
+@jax.named_scope("attention")
 def apply_cross_attention(p, cfg, x, enc_kv, dtype):
     """Cross-attention over precomputed encoder K/V (whisper decoder)."""
     B, T = x.shape[:2]
     H, hd = cfg.num_heads, cfg.head_dim
-    q = M.apply_dense(p["wq"], x, dtype).reshape(B, T, H, hd)
+    with jax.named_scope("qkv"):
+        q = M.apply_dense(p["wq"], x, dtype).reshape(B, T, H, hd)
     out = attend(q, enc_kv.k, enc_kv.v, causal=False)
-    return M.apply_dense(p["wo"], out.reshape(B, T, -1), dtype)
+    with jax.named_scope("out_proj"):
+        return M.apply_dense(p["wo"], out.reshape(B, T, -1), dtype)
 
 
+@jax.named_scope("attention")
 def cross_kv(p, cfg, enc_out, dtype) -> KVCache:
     B, S = enc_out.shape[:2]
     KV, hd = cfg.num_kv_heads, cfg.head_dim

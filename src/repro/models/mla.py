@@ -64,6 +64,7 @@ def _latent(p, cfg, x, positions, dtype):
     return c_kv, k_pe
 
 
+@jax.named_scope("attention")
 def apply_mla(p, cfg, x, *, positions, dtype):
     """Full-sequence (train / prefill): expand latents to per-head K/V."""
     m, H = cfg.mla, cfg.num_heads
@@ -94,6 +95,7 @@ def apply_mla(p, cfg, x, *, positions, dtype):
     return M.apply_dense(p["wo"], out, dtype)
 
 
+@jax.named_scope("attention")
 def apply_mla_decode(p, cfg, x, cache: MLACache, pos, dtype, mode="absorb"):
     m, H = cfg.mla, cfg.num_heads
     B = x.shape[0]

@@ -269,6 +269,7 @@ def mlp_init(key, d: int, d_ff: int, act: str, *, ff_axis: str = "ffn",
     return p
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p, x, act: str, dtype):
     from repro.core.partitioning import constrain
     from repro.dist import tp as _tp

@@ -345,6 +345,7 @@ def _run_groups(params_groups, groups, cfg, rt, x, *, positions, states,
 # --------------------------------------------------------------------------
 # Input embedding (+ modality frontend stubs)
 # --------------------------------------------------------------------------
+@jax.named_scope("embed")
 def embed_inputs(p, cfg, batch, dtype, offset=0):
     x = M.apply_embed(p["embed"], batch["tokens"], dtype)
     if cfg.frontend == "vision" and "frontend" in batch:
@@ -360,7 +361,9 @@ def embed_inputs(p, cfg, batch, dtype, offset=0):
     return x
 
 
+@jax.named_scope("readout")
 def readout(p, cfg, x, dtype):
+    """Final norm and LM head."""
     x = M.apply_norm(p["final_norm"], x, cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = M.apply_unembed(p["embed"], x, dtype)

@@ -4,7 +4,11 @@ Four layers over the serving stack (DESIGN.md §7, §9):
 
   tracer    — ring-buffer ``Tracer``: per-request spans + allocator events
               + counters, exported as JSON-lines or Chrome trace-event
-              format (opens in Perfetto, one track per engine slot)
+              format (opens in Perfetto, one track per engine slot;
+              ``launch/serve.py --trace-out`` writes it); ``tracer.Phase``,
+              the engine's one phase mechanism, also opens a ``jax.profiler``
+              annotation ``engine.<phase>`` per cycle phase, so a run under
+              ``jax.profiler.trace`` shows the phases beside the device ops
   workload  — seeded replayable traces (bursty / diurnal / heavy-tail
               arrival + length distributions) and ``Replayer``, which
               drives any engine config against the arrival schedule and

@@ -18,13 +18,24 @@ Two export formats:
 
 Timestamps are ``time.perf_counter()`` seconds relative to tracer creation
 (exported as microseconds, the Chrome unit).
+
+``Phase`` is the engine's one phase mechanism: each phase of an engine
+cycle is stamped once and sent to every sink present — a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` (so a profiler
+trace shows the phases on the device trace's clock, beside the device ops
+they dispatched), the ``DispatchProfiler`` phase, and a ``Tracer`` span.
+``annotate_gc`` adds an ``engine.gc`` annotation round every Python
+garbage collection.
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
 from collections import Counter, deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple, Union
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # event phases (Chrome trace-event vocabulary)
 INSTANT = "i"
@@ -177,6 +188,78 @@ class Tracer:
         with open(path, "w") as f:
             json.dump({"traceEvents": self.chrome_events(),
                        "displayTimeUnit": "ms"}, f)
+
+
+class Phase:
+    """One phase of the serving engine, stamped once on the host's
+    ``perf_counter`` clock and sent to every sink present:
+
+    * ``TraceAnnotation("engine.<name>")`` (``step=n``: a
+      ``StepTraceAnnotation`` with ``step_num=n``), recorded when a
+      ``jax.profiler`` trace runs; otherwise it costs a check of the
+      profiler's enabled flag;
+    * the ``DispatchProfiler`` phase ``prof`` (keyed ``key``), when
+      ``profiler`` is given;
+    * the ``Tracer`` span ``span`` on ``track`` with ``args``, when
+      ``tracer`` is given.
+
+    ``t0`` and ``t1`` are the two stamps (``t1`` set on exit), read by the
+    engine's own time counters."""
+
+    __slots__ = ("_ann", "_prof", "_tracer", "_span", "_track", "_args",
+                 "t0", "t1")
+
+    def __init__(self, name: str, *, step: Optional[int] = None,
+                 profiler=None, prof: Optional[str] = None, key: Any = None,
+                 tracer: Optional[Tracer] = None, span: Optional[str] = None,
+                 track: Track = "engine", **args):
+        name = "engine." + name
+        self._ann = (TraceAnnotation(name) if step is None
+                     else StepTraceAnnotation(name, step_num=step))
+        self._prof = (profiler.phase(prof, key=key)
+                      if profiler is not None and prof is not None else None)
+        self._tracer = tracer if span is not None else None
+        self._span, self._track, self._args = span, track, args
+
+    def __enter__(self) -> "Phase":
+        self._ann.__enter__()
+        if self._prof is not None:
+            self._prof.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        try:
+            if self._prof is not None:
+                self._prof.__exit__(*exc)
+            tr = self._tracer
+            if tr is not None:
+                tr.span(self._span, self._track, tr.rel(self.t0),
+                        tr.rel(self.t1), **self._args)
+        finally:
+            self._ann.__exit__(*exc)
+        return False
+
+
+_gc_open: List[TraceAnnotation] = []     # the collection running now
+
+
+def _gc_phase(phase: str, info: Dict[str, int]):
+    if phase == "start":
+        ann = TraceAnnotation("engine.gc", generation=info["generation"])
+        ann.__enter__()
+        _gc_open.append(ann)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def annotate_gc():
+    """Put an ``engine.gc`` annotation (with the collected generation)
+    round every Python garbage collection, process-wide; idempotent.  Costs
+    nothing while no collection runs."""
+    if _gc_phase not in gc.callbacks:
+        gc.callbacks.append(_gc_phase)
 
 
 def span_pairs(events: Iterable[Tuple], open_name: str,

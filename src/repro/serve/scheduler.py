@@ -30,7 +30,6 @@ host-side control around jitted step functions, as production engines do.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 import warnings
@@ -41,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracer import Phase, annotate_gc
 from repro.serve import speculate as SP
 from repro.serve.config import EngineConfig
 from repro.serve.kvcache import (CacheBackend, PagedBackend, bucket_length,
@@ -151,7 +151,17 @@ class ServingEngine:
         brackets every step submission in a phase context (``admit`` /
         ``bucketed_prefill`` / ``chunk_prefill`` / ``decode`` /
         ``collective`` under TP) so registry-kernel dispatches and
-        measured step walls aggregate per phase (None: zero overhead)."""
+        measured step walls aggregate per phase (None: zero overhead).
+
+        Every phase of a cycle also opens a ``jax.profiler``
+        annotation ``engine.<phase>`` (``_phase``), so a profiler trace
+        shows where the host was while the device ran or sat idle:
+        ``engine.step`` round each cycle, and in it ``consume`` (its
+        blocking read ``stream_out``), ``admit``, ``chunk``,
+        ``first_token``, ``decode_submit`` and ``finish``; ``prefill`` on
+        the bucketed path; ``draft`` (its passes ``draft_step``,
+        ``draft_catchup``) and ``verify`` when speculating; ``engine.gc``
+        round each Python garbage collection."""
         if legacy:
             if config is not None:
                 raise TypeError(
@@ -185,6 +195,7 @@ class ServingEngine:
         self.model = model
         self.tracer = tracer
         self.profiler = profiler
+        annotate_gc()
         self.slots = slots
         self.cache_len = cache_len
         self.params = params
@@ -421,12 +432,11 @@ class ServingEngine:
     def _free_slots(self) -> List[int]:
         return [s for s, r in self.active.items() if r is None]
 
-    def _phase(self, name: str, key=None):
-        """Profiler phase context for one engine step (no-op without a
-        profiler — one attr check, like the tracer sites)."""
-        if self.profiler is None:
-            return contextlib.nullcontext()
-        return self.profiler.phase(name, key=key)
+    def _phase(self, name: str, **kw) -> Phase:
+        """One engine phase (``repro.obs.tracer.Phase``): the
+        ``engine.<name>`` annotation, plus this engine's profiler phase
+        ``kw["prof"]`` and tracer span ``kw["span"]`` where given."""
+        return Phase(name, profiler=self.profiler, tracer=self.tracer, **kw)
 
     def _admit_group(self, group, slots_for) -> List[Request]:
         """One bucketed batched prefill for ``group`` (list of Requests);
@@ -438,31 +448,27 @@ class ServingEngine:
             bucket = max(bucket_length(r.prompt_len, self.min_bucket,
                                        self.cache_len) for r in group)
         Bp = self.prefill_batch
-        tokens = np.zeros((Bp, bucket), np.int32)
-        lengths = np.ones((Bp,), np.int32)
-        for i, req in enumerate(group):
-            tokens[i, :req.prompt_len] = req.prompt
-            lengths[i] = self._front + req.prompt_len
-        batch = {"tokens": jnp.asarray(tokens),
-                 "length": jnp.asarray(lengths)}
-        if self.prefill_extras is not None:
-            extras: Dict[str, Any] = {}
-            per_req = [self.prefill_extras(r) for r in group]
-            for k in per_req[0]:
-                rows = [e[k] for e in per_req]
-                rows += [rows[-1]] * (Bp - len(rows))   # pad batch rows
-                extras[k] = jnp.concatenate(rows, axis=0)
-            batch.update(extras)
-
-        t0 = time.perf_counter()
-        with self._phase("bucketed_prefill", key=bucket):
+        with self._phase("prefill", prof="bucketed_prefill", key=bucket,
+                         span="prefill", bucket=bucket,
+                         batch=len(group)) as ph:
+            tokens = np.zeros((Bp, bucket), np.int32)
+            lengths = np.ones((Bp,), np.int32)
+            for i, req in enumerate(group):
+                tokens[i, :req.prompt_len] = req.prompt
+                lengths[i] = self._front + req.prompt_len
+            batch = {"tokens": jnp.asarray(tokens),
+                     "length": jnp.asarray(lengths)}
+            if self.prefill_extras is not None:
+                extras: Dict[str, Any] = {}
+                per_req = [self.prefill_extras(r) for r in group]
+                for k in per_req[0]:
+                    rows = [e[k] for e in per_req]
+                    rows += [rows[-1]] * (Bp - len(rows))  # pad batch rows
+                    extras[k] = jnp.concatenate(rows, axis=0)
+                batch.update(extras)
             next_tok, prefill_caches = self.prefill_step(self.params, batch)
             next_tok = np.asarray(next_tok)
         self.prefill_calls += 1
-        if self.tracer is not None:
-            self.tracer.span("prefill", "engine", self.tracer.rel(t0),
-                             self.tracer.now(), bucket=bucket,
-                             batch=len(group))
 
         finished: List[Request] = []
         for i, req in enumerate(group):
@@ -498,7 +504,7 @@ class ServingEngine:
                 finished.append(self._finish(slot, req))
             else:
                 self._decoding.add(slot)
-        self.prefill_s += time.perf_counter() - t0
+        self.prefill_s += time.perf_counter() - ph.t0
         return finished
 
     def _admit(self) -> List[Request]:
@@ -510,7 +516,7 @@ class ServingEngine:
             free = self._free_slots()
             if not free:
                 break
-            with self._phase("admit"):
+            with self._phase("admit", prof="admit"):
                 group, slots_for = self._gather_group(free)
             if not group:
                 break
@@ -542,7 +548,7 @@ class ServingEngine:
         """Assign slots + pages to queued requests, strictly FIFO: a
         request the pool cannot hold right now *blocks* admission (no
         overtaking — the starvation guard) until releases free pages."""
-        with self._phase("admit"):
+        with self._phase("admit", prof="admit"):
             self._admit_chunked_locked(count_defer)
 
     def _admit_chunked_locked(self, count_defer: bool):
@@ -611,37 +617,36 @@ class ServingEngine:
         off = self._chunk_off[slot]
         end = min(off + C, req.prompt_len)
         valid = end - off
-        tokens = np.zeros((1, C), np.int32)
-        tokens[0, :valid] = req.prompt[off:end]
-        batch = {
-            "tokens": jnp.asarray(tokens),
-            "offset": jnp.asarray([off], jnp.int32),
-            "valid": jnp.asarray([valid], jnp.int32),
-            "stage_base": jnp.asarray([self._stage_base[slot]], jnp.int32),
-            "block_tables": jnp.asarray(
-                self.backend.block_tables[slot:slot + 1]),
-        }
-        t0 = time.perf_counter()
-        with self._phase("chunk_prefill"):
+        with self._phase("chunk", prof="chunk_prefill", span="chunk",
+                         track=slot, rid=req.rid, off=off,
+                         valid=valid) as ph:
+            tokens = np.zeros((1, C), np.int32)
+            tokens[0, :valid] = req.prompt[off:end]
+            batch = {
+                "tokens": jnp.asarray(tokens),
+                "offset": jnp.asarray([off], jnp.int32),
+                "valid": jnp.asarray([valid], jnp.int32),
+                "stage_base": jnp.asarray([self._stage_base[slot]],
+                                          jnp.int32),
+                "block_tables": jnp.asarray(
+                    self.backend.block_tables[slot:slot + 1]),
+            }
             next_tok, self.caches = self.chunk_step(
                 self.params, batch, self.caches)
-        self.prefill_s += time.perf_counter() - t0
+        self.prefill_s += ph.t1 - ph.t0
         self.chunk_calls += 1
         self.chunk_tokens += valid
         self._chunk_off[slot] = end
-        if self.tracer is not None:
-            self.tracer.span("chunk", slot, self.tracer.rel(t0),
-                             self.tracer.now(), rid=req.rid, off=off,
-                             valid=valid)
         if end < req.prompt_len:
             return []
         # prompt fully on-pool: index its pages for prefix reuse, start
         # decoding from its first generated token
-        self._prefilling.popleft()
-        if self.backend.prefix_cache:
-            self.backend.register_prefix(slot, req.prompt)
+        with self._phase("first_token"):
+            self._prefilling.popleft()
+            if self.backend.prefix_cache:
+                self.backend.register_prefix(slot, req.prompt)
+            tok = int(np.asarray(next_tok)[0])
         self.prefill_calls += 1
-        tok = int(np.asarray(next_tok)[0])
         req.out.append(tok)
         req.first_token_step = self.steps
         req.first_token_t = time.perf_counter()
@@ -662,11 +667,12 @@ class ServingEngine:
         req.finish_t = time.perf_counter()
         self.active[slot] = None
         self._decoding.discard(slot)
-        self.backend.release(slot)
-        if self.spec_k and slot in self._draft_ready:
-            self._draft_backend.release(slot)
-            self._draft_ready.discard(slot)
-            self._draft_pending.pop(slot, None)
+        with self._phase("finish"):
+            self.backend.release(slot)
+            if self.spec_k and slot in self._draft_ready:
+                self._draft_backend.release(slot)
+                self._draft_ready.discard(slot)
+                self._draft_pending.pop(slot, None)
         self.requests_finished += 1
         # latency samples: only requests that actually emitted a first
         # token have a TTFT, and only multi-token requests have a decode
@@ -707,32 +713,28 @@ class ServingEngine:
     def _submit_decode(self):
         """Enqueue one decode step over the decoding slots and return
         without blocking (JAX async dispatch): the device futures, the
-        decoding-slot snapshot and the submit timestamps park in
-        ``_inflight`` until ``_consume`` streams the tokens out."""
-        batch = {"tokens": jnp.asarray(self.last_tok[:, None]),
-                 "pos": jnp.asarray(self.pos),
-                 "sample_nonce": jnp.asarray(self._nonce)}
-        extras = self.backend.batch_extras()
-        if "block_tables" in extras:
-            extras["block_tables"] = self._decode_block_tables()
-        batch.update(extras)
-        t0 = time.perf_counter()
-        with self._phase("decode"):
+        decoding-slot snapshot and the submit phase (its two stamps) park
+        in ``_inflight`` until ``_consume`` streams the tokens out."""
+        slots = tuple(sorted(self._decoding))
+        with self._phase("decode_submit", prof="decode", span="device_submit",
+                         batch=len(slots)) as sub:
+            batch = {"tokens": jnp.asarray(self.last_tok[:, None]),
+                     "pos": jnp.asarray(self.pos),
+                     "sample_nonce": jnp.asarray(self._nonce)}
+            extras = self.backend.batch_extras()
+            if "block_tables" in extras:
+                extras["block_tables"] = self._decode_block_tables()
+            batch.update(extras)
             next_tok, self.caches = self.serve_step(
                 self.params, batch, self.caches)
-        t_sub = time.perf_counter()
         # streamed-bytes model: this step reads every cached row of every
         # decoding slot once; a head-sharded pool streams 1/kv_shards of
         # each row per device
-        rows = int(sum(int(self.pos[s]) + 1 for s in self._decoding))
+        rows = int(sum(int(self.pos[s]) + 1 for s in slots))
         self.kv_bytes_streamed += rows * self._kv_row_bytes
         self.kv_bytes_streamed_per_device += rows * (
             self._kv_row_bytes // max(self._kv_shards, 1))
-        if self.tracer is not None:
-            self.tracer.span("device_submit", "engine", self.tracer.rel(t0),
-                             self.tracer.rel(t_sub),
-                             batch=len(self._decoding))
-        self._inflight = (next_tok, tuple(sorted(self._decoding)), t0, t_sub)
+        self._inflight = (next_tok, slots, sub)
 
     def _consume(self) -> List[Request]:
         """Block on the in-flight decode step's tokens (the engine's only
@@ -740,30 +742,33 @@ class ServingEngine:
         decoding at submit time."""
         if self._inflight is None:
             return []
-        next_tok, slots, t0, t_sub = self._inflight
+        with self._phase("consume"):
+            return self._consume_inflight()
+
+    def _consume_inflight(self) -> List[Request]:
+        next_tok, slots, sub = self._inflight
         self._inflight = None
-        t_wait = time.perf_counter()
-        toks = np.asarray(next_tok)[:, 0]          # stream-out: blocks
-        t_done = time.perf_counter()
+        with self._phase("stream_out", span="stream_out",
+                         batch=len(slots)) as out:
+            toks = np.asarray(next_tok)[:, 0]      # blocks on the device
+        wait = out.t1 - out.t0
         # host work done between submit and here overlapped the device
         # step — but only the async pipeline actually interleaves any;
         # the sync path consumes immediately and must report ~0 overlap
         if self._async:
-            self.host_overlap_s += max(0.0, t_wait - t_sub)
-        self.stream_wait_s += t_done - t_wait
+            self.host_overlap_s += max(0.0, out.t0 - sub.t1)
+        self.stream_wait_s += wait
         if self.profiler is not None:
             # the submit span landed in the decode phase; the stream-out
             # wait is the rest of the step's measured wall
-            self.profiler.add_wall("decode", t_done - t_wait)
+            self.profiler.add_wall("decode", wait)
         # decode_s counts host time attributable to decode (submit + wait,
         # not the overlapped window) so prefill_s + decode_s ~= wall time
-        self.decode_s += (t_sub - t0) + (t_done - t_wait)
+        self.decode_s += (sub.t1 - sub.t0) + wait
         self.decode_steps += 1
         if self.tracer is not None:
-            self.tracer.span("stream_out", "engine", self.tracer.rel(t_wait),
-                             self.tracer.rel(t_done), batch=len(slots))
-            self.tracer.span("decode", "engine", self.tracer.rel(t0),
-                             self.tracer.rel(t_done), batch=len(slots))
+            self.tracer.span("decode", "engine", self.tracer.rel(sub.t0),
+                             self.tracer.rel(out.t1), batch=len(slots))
         finished: List[Request] = []
         for slot in slots:
             req = self.active[slot]
@@ -801,7 +806,7 @@ class ServingEngine:
                  "valid": jnp.asarray(valid),
                  "stage_base": jnp.zeros((self.slots,), jnp.int32),
                  "block_tables": jnp.asarray(bt * mask)}
-        with self._phase("draft"):
+        with self._phase("draft_step", prof="draft"):
             logits, self.draft_caches = self.draft_step(
                 self.draft_params, batch, self.draft_caches)
             logits = np.asarray(logits)
@@ -819,13 +824,13 @@ class ServingEngine:
                                "full occupancy, so this cannot happen")
         W = self._draft_W
         prompt = [int(t) for t in req.prompt]
-        t0 = time.perf_counter()
-        off = 0
-        while off < len(prompt):
-            end = min(off + W, len(prompt))
-            self._draft_forward({slot: prompt[off:end]}, {slot: off})
-            off = end
-        self.prefill_s += time.perf_counter() - t0
+        with self._phase("draft_catchup") as ph:
+            off = 0
+            while off < len(prompt):
+                end = min(off + W, len(prompt))
+                self._draft_forward({slot: prompt[off:end]}, {slot: off})
+                off = end
+        self.prefill_s += ph.t1 - ph.t0
         self._draft_pos[slot] = len(prompt)
         self._draft_pending[slot] = [int(self.last_tok[slot])]
         self._draft_ready.add(slot)
@@ -873,48 +878,46 @@ class ServingEngine:
         cur = {s: int(self._draft_pos[s]) for s in slots}
         feed = {s: list(self._draft_pending[s])
                 for s in slots if k_eff[s] > 0}
-        t_draft = time.perf_counter()
         kmax = max(k_eff.values(), default=0)
-        for j in range(1, kmax + 1):
-            if not feed:
-                break
-            logits = self._draft_forward(feed, cur)
-            nxt: Dict[int, List[int]] = {}
-            for s, toks in feed.items():
-                if j == 1:
-                    fed[s] = len(toks)
-                cur[s] += len(toks)
-                row = logits[s]
-                if self._temperature > 0:
-                    p = SP.softmax(row, self._temperature)
-                    d = int(self._spec_rng.choice(p.shape[0], p=p))
-                    dists[s].append(p)
-                else:
-                    d = int(np.argmax(row))
-                drafts[s].append(d)
-                if k_eff[s] > j:
-                    nxt[s] = [d]
-            feed = nxt
-        if self.tracer is not None and kmax:
-            self.tracer.span("draft", "engine", self.tracer.rel(t_draft),
-                             self.tracer.now(), batch=len(slots), k=kmax)
+        with self._phase("draft", span="draft" if kmax else None,
+                         batch=len(slots), k=kmax):
+            for j in range(1, kmax + 1):
+                if not feed:
+                    break
+                logits = self._draft_forward(feed, cur)
+                nxt: Dict[int, List[int]] = {}
+                for s, toks in feed.items():
+                    if j == 1:
+                        fed[s] = len(toks)
+                    cur[s] += len(toks)
+                    row = logits[s]
+                    if self._temperature > 0:
+                        p = SP.softmax(row, self._temperature)
+                        d = int(self._spec_rng.choice(p.shape[0], p=p))
+                        dists[s].append(p)
+                    else:
+                        d = int(np.argmax(row))
+                    drafts[s].append(d)
+                    if k_eff[s] > j:
+                        nxt[s] = [d]
+                feed = nxt
 
         # 3) one target pass scores every window: logits row i is the
         # target distribution conditioned on the first i draft tokens
-        tokens = np.zeros((self.slots, W), np.int32)
-        valid = np.zeros((self.slots,), np.int32)
-        offs = np.zeros((self.slots,), np.int32)
-        for s in slots:
-            win = [int(self.last_tok[s])] + drafts[s]
-            tokens[s, :len(win)] = win
-            valid[s] = len(win)
-            offs[s] = int(self.pos[s])
-        batch = {"tokens": jnp.asarray(tokens),
-                 "offset": jnp.asarray(offs),
-                 "valid": jnp.asarray(valid),
-                 "block_tables": self._decode_block_tables()}
-        t_ver = time.perf_counter()
-        with self._phase(f"verify@{self.spec_k}"):
+        with self._phase("verify", prof=f"verify@{self.spec_k}",
+                         span="verify", batch=len(slots)):
+            tokens = np.zeros((self.slots, W), np.int32)
+            valid = np.zeros((self.slots,), np.int32)
+            offs = np.zeros((self.slots,), np.int32)
+            for s in slots:
+                win = [int(self.last_tok[s])] + drafts[s]
+                tokens[s, :len(win)] = win
+                valid[s] = len(win)
+                offs[s] = int(self.pos[s])
+            batch = {"tokens": jnp.asarray(tokens),
+                     "offset": jnp.asarray(offs),
+                     "valid": jnp.asarray(valid),
+                     "block_tables": self._decode_block_tables()}
             logits, self.caches = self.verify_step(
                 self.params, batch, self.caches)
             logits = np.asarray(logits)
@@ -922,9 +925,6 @@ class ServingEngine:
         self.kv_bytes_streamed += rows * self._kv_row_bytes
         self.kv_bytes_streamed_per_device += rows * (
             self._kv_row_bytes // max(self._kv_shards, 1))
-        if self.tracer is not None:
-            self.tracer.span("verify", "engine", self.tracer.rel(t_ver),
-                             self.tracer.now(), batch=len(slots))
 
         # 4) host accept/reject + emission (finish rules identical to
         # ``_consume``)
@@ -1013,6 +1013,10 @@ class ServingEngine:
         Returns the requests that finished this cycle, or ``None`` when the
         engine is idle (nothing active after admission).
         """
+        with self._phase("step", step=self.steps):
+            return self._cycle()
+
+    def _cycle(self) -> Optional[List[Request]]:
         finished: List[Request] = []
         if self.chunked:
             if self._inflight is not None:

@@ -14,6 +14,10 @@ bucket instead of one per distinct prompt length.
 hot kernels at the serving shapes (from the persistent tune cache, heuristic
 defaults when untuned) so the serving layer and kernel-backed model paths
 read tuned configs from one place.
+
+The token choice of every step (argmax or sampling) runs under the
+``jax.named_scope`` ``sample``, beside the model's ``embed``,
+``attention``, ``mlp`` and ``readout`` scopes.
 """
 from __future__ import annotations
 
@@ -103,7 +107,8 @@ def make_chunk_step(model):
     token)."""
     def chunk_step(params, batch, caches):
         logits, caches = model.chunk_step(params, batch, caches)  # (B, V)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
+        with jax.named_scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
     return chunk_step
 
 
@@ -140,15 +145,16 @@ def make_prefill_step(model):
         length = batch.get("length")
         feed = {k: v for k, v in batch.items() if k != "length"}
         logits, caches = model.prefill(params, feed)
-        if length is None:
-            next_tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None]
+        with jax.named_scope("sample"):
+            if length is None:
+                next_tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None]
+                return next_tok.astype(jnp.int32), caches
+            # gather each row's last valid position first: O(Bp*V) argmax
+            # instead of O(Bp*L*V) over positions that are then discarded
+            last = jnp.take_along_axis(
+                logits, (length - 1)[:, None, None], axis=1)[:, 0]  # (Bp, V)
+            next_tok = jnp.argmax(last, axis=-1)
             return next_tok.astype(jnp.int32), caches
-        # gather each row's last valid position first: O(Bp*V) argmax
-        # instead of O(Bp*L*V) over positions that are then discarded
-        last = jnp.take_along_axis(
-            logits, (length - 1)[:, None, None], axis=1)[:, 0]   # (Bp, V)
-        next_tok = jnp.argmax(last, axis=-1)
-        return next_tok.astype(jnp.int32), caches
     return prefill_step
 
 
@@ -175,14 +181,17 @@ def make_serve_step(model, *, temperature: float = 0.0, seed: int = 0,
     returned step for kernel-backed decode paths and introspection."""
     def serve_step(params, batch, caches):
         logits, caches = model.decode_step(params, batch, caches)
-        if temperature > 0:
-            keys = sample_keys(batch["pos"], batch["pos"].shape[0], seed,
-                               nonce=batch.get("sample_nonce"))
-            next_tok = jax.vmap(
-                lambda k, row: jax.random.categorical(k, row / temperature)
-            )(keys, logits[:, -1, :])[:, None].astype(jnp.int32)
-        else:
-            next_tok = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if temperature > 0:
+                keys = sample_keys(batch["pos"], batch["pos"].shape[0], seed,
+                                   nonce=batch.get("sample_nonce"))
+                next_tok = jax.vmap(
+                    lambda k, row: jax.random.categorical(k,
+                                                          row / temperature)
+                )(keys, logits[:, -1, :])[:, None].astype(jnp.int32)
+            else:
+                next_tok = jnp.argmax(logits[:, -1:, :],
+                                      axis=-1).astype(jnp.int32)
         return next_tok, caches
     serve_step.troop_configs = troop_configs
     return serve_step
