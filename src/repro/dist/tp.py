@@ -264,7 +264,7 @@ def _pathkeys(path) -> Tuple[str, ...]:
 
 
 def _arr_spec(leaf, plan: TPPlan):
-    """Spec for one cache/state array: every KV-bearing leaf is
+    """Spec for one per-slot cache/state array: every KV-bearing leaf is
     (..., KV, hd) or (..., KV, 1) — shard axis ``ndim - 2`` when the plan
     shards KV, else replicate.  Non-cache leaves (tokens, logits, tables)
     are < 4-D and stay replicated."""
@@ -276,8 +276,30 @@ def _arr_spec(leaf, plan: TPPlan):
     return P()
 
 
+def _pool_spec(pool, plan: TPPlan):
+    """Spec for one lane-dense page pool, (..., P, page, KV*hd) or a scale
+    pool (..., P, page, KV): the last axis is head-major, so an even split
+    of it gives each device whole heads."""
+    if pool is None:
+        return None
+    if plan.kv_shards > 1:
+        return P(*([None] * (pool.ndim - 1) + [plan.axis]))
+    return P()
+
+
 def cache_specs(caches, plan: TPPlan):
-    return jax.tree.map(lambda l: _arr_spec(l, plan), caches)
+    """Spec tree of a cache tree (or of any tree that holds caches, such as
+    a step's output): paged leaves by ``_pool_spec``, the rest by
+    ``_arr_spec``."""
+    from repro.serve.kvcache import PagedKVCache
+
+    def spec(leaf):
+        if isinstance(leaf, PagedKVCache):
+            return PagedKVCache(*(_pool_spec(p, plan) for p in leaf))
+        return _arr_spec(leaf, plan)
+
+    return jax.tree.map(spec, caches,
+                        is_leaf=lambda x: isinstance(x, PagedKVCache))
 
 
 # -------------------------------------------------------------- executor
@@ -310,9 +332,8 @@ class TPExecutor:
 
     def shard_caches(self, caches):
         return jax.tree.map(
-            lambda l: jax.device_put(
-                l, NamedSharding(self.mesh, _arr_spec(l, self.plan))),
-            caches)
+            lambda l, s: jax.device_put(l, NamedSharding(self.mesh, s)),
+            caches, cache_specs(caches, self.plan))
 
     # ---------------------------------------------------------- steps
     def jit_step(self, fn: Callable, *, probe: Optional[Callable] = None,
@@ -331,8 +352,7 @@ class TPExecutor:
             if len(args) > 2:
                 in_specs.append(cache_specs(args[2], plan_))
             out_shape = jax.eval_shape(probe or fn, *args)
-            out_specs = jax.tree.map(
-                lambda l: _arr_spec(l, plan_), out_shape)
+            out_specs = cache_specs(out_shape, plan_)
 
             def body(*a):
                 with enter(plan_):

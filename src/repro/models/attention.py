@@ -268,70 +268,115 @@ def update_cache(cache_arr, new, pos):
                      out_specs=cspec, check_rep=False)(cache_arr, row, pos)
 
 
-def update_paged_cache(pool, new, pos, block_tables):
+def _set_rows(pool, layer, pid, row, rows):
+    """Scatter ``rows`` at (page ``pid``, row ``row``) of the pool, or of
+    its ``layer`` index when the pool is a stacked group's (L, P, page, W)
+    carry: the write lands in the one carried buffer, in place."""
+    idx = (pid, row) if layer is None else (layer, pid, row)
+    return pool.at[idx].set(rows, mode="drop")
+
+
+def _rows(new, pool):
+    """(B, T, KV, hd|1) new rows as the pool's lane-dense (B, T, W) rows."""
+    return new.reshape(new.shape[:2] + (-1,)).astype(pool.dtype)
+
+
+def update_paged_cache(pool, new, pos, block_tables, layer=None):
     """Paged cache write: route the new token row through the block table.
 
-    pool (P, page, KV, hd); new (B, 1, KV, hd); pos (B,); block_tables
-    (B, nblk).  Token ``pos`` of slot ``b`` lives at page
-    ``block_tables[b, pos // page]`` row ``pos % page`` — O(tokens) bytes,
-    no full-cache rewrite, and (unlike the dense scatter) the write lands in
-    a page that is physically disjoint from every other slot's pages.
+    pool (P, page, W), or (L, P, page, W) written at index ``layer``; W is
+    KV*hd (value pools) or KV (scale pools); new (B, 1, KV, hd|1); pos
+    (B,); block_tables (B, nblk).  Token ``pos`` of slot ``b`` lives at
+    page ``block_tables[b, pos // page]`` row ``pos % page`` — O(tokens)
+    bytes, no full-cache rewrite, and (unlike the dense scatter) the write
+    lands in a page that is physically disjoint from every other slot's
+    pages.
     """
-    page = pool.shape[1]
-    row = new[:, 0].astype(pool.dtype)
+    page = pool.shape[-2]
+    row = _rows(new, pool)[:, 0]
     pid = jnp.take_along_axis(block_tables, (pos // page)[:, None],
                               axis=1)[:, 0]
-    return pool.at[pid, pos % page].set(row, mode="drop")
+    return _set_rows(pool, layer, pid, pos % page, row)
 
 
-def update_paged_cache_chunk(pool, new, offset, valid, block_tables):
+def update_paged_cache_chunk(pool, new, offset, valid, block_tables,
+                             layer=None):
     """Chunked-prefill cache write: scatter a slab of token rows through the
     block table.
 
-    pool (P, page, KV, hd); new (B, C, KV, hd); offset (B,) absolute
-    position of row 0; valid (B,) rows of the slab that are real tokens;
-    block_tables (B, nblk).  Row r of slot b lands at page
-    ``block_tables[b, (offset+r) // page]`` row ``(offset+r) % page``; pad
-    rows (r >= valid) are redirected to the never-read NULL page, so a
-    partially filled final chunk cannot clobber live pages — in particular
-    never a *shared* prefix page, which by the COW invariant is only ever
-    mapped at positions < offset.
+    pool (P, page, W) or (L, P, page, W) as in ``update_paged_cache``; new
+    (B, C, KV, hd|1); offset (B,) absolute position of row 0; valid (B,)
+    rows of the slab that are real tokens; block_tables (B, nblk).  Row r
+    of slot b lands at page ``block_tables[b, (offset+r) // page]`` row
+    ``(offset+r) % page``; pad rows (r >= valid) are redirected to the
+    never-read NULL page, so a partially filled final chunk cannot clobber
+    live pages — in particular never a *shared* prefix page, which by the
+    COW invariant is only ever mapped at positions < offset.
     """
-    page = pool.shape[1]
+    page = pool.shape[-2]
     B, C = new.shape[:2]
     pos = offset[:, None] + jnp.arange(C)[None, :]             # (B, C)
     blk = jnp.clip(pos // page, 0, block_tables.shape[1] - 1)
     pid = jnp.take_along_axis(block_tables, blk, axis=1)       # (B, C)
     pid = jnp.where(jnp.arange(C)[None, :] < valid[:, None], pid, NULL_PAGE)
-    rows = new.astype(pool.dtype).reshape((B * C,) + new.shape[2:])
-    return pool.at[pid.reshape(-1), (pos % page).reshape(-1)].set(
-        rows, mode="drop")
+    rows = _rows(new, pool).reshape(B * C, -1)
+    return _set_rows(pool, layer, pid.reshape(-1),
+                     (pos % page).reshape(-1), rows)
+
+
+def _write_paged(cache: PagedKVCache, write, k_new, v_new) -> PagedKVCache:
+    """Write new K/V rows into every pool of ``cache`` with ``write(pool,
+    rows)``; int8 pools quantize the rows and write value and scale pages
+    through the same table entries (§Perf A4 at page granularity)."""
+    if not cache.quantized:
+        return PagedKVCache(write(cache.k_pool, k_new),
+                            write(cache.v_pool, v_new))
+    k8, ks = quantize_kv(k_new)
+    v8, vs = quantize_kv(v_new)
+    return PagedKVCache(write(cache.k_pool, k8), write(cache.v_pool, v8),
+                        write(cache.k_scale_pool, ks),
+                        write(cache.v_scale_pool, vs))
+
+
+def _kernel_pool(pool, layer, hd):
+    """One layer's pool as the (P, page, KV, hd|1) array the Pallas kernels
+    take (a per-layer copy when ``pool`` is a stacked carry)."""
+    if layer is not None:
+        pool = pool[layer]
+    return pool.reshape(pool.shape[:2] + (-1, hd))
 
 
 @jax.named_scope("kv_gather")
-def gather_paged_kv(cache: PagedKVCache, block_tables,
-                    dtype=jnp.bfloat16):
+def gather_paged_kv(cache: PagedKVCache, block_tables, head_dim: int,
+                    dtype=jnp.bfloat16, layer=None):
     """Dense logical view of a paged cache: (B, nblk*page, KV, hd).
 
-    Pure-jnp reference path (the oracle for the Pallas
-    ``paged_decode_attention`` kernels, which stream pages directly from
-    the pool without materializing this view).  int8 pools are dequantized
-    through their gathered scale pages (to ``dtype``).
+    One gather per pool at ``block_tables`` (at ``(layer, block_tables)``
+    of a stacked carry).  Pure-jnp reference path (the oracle for the
+    Pallas ``paged_decode_attention`` kernels, which stream pages directly
+    from the pool without materializing this view).  int8 pools are
+    dequantized through their gathered scale pages (to ``dtype``).
     """
     B, nblk = block_tables.shape
-    page, KV, hd = cache.k_pool.shape[1:]
-    k = cache.k_pool[block_tables].reshape(B, nblk * page, KV, hd)
-    v = cache.v_pool[block_tables].reshape(B, nblk * page, KV, hd)
+    idx = block_tables if layer is None else (layer, block_tables)
+
+    def view(pool, hd):
+        return pool[idx].reshape(B, nblk * pool.shape[-2], -1, hd)
+
+    k, v = view(cache.k_pool, head_dim), view(cache.v_pool, head_dim)
     if cache.quantized:
-        ks = cache.k_scale_pool[block_tables].reshape(B, nblk * page, KV, 1)
-        vs = cache.v_scale_pool[block_tables].reshape(B, nblk * page, KV, 1)
+        ks, vs = view(cache.k_scale_pool, 1), view(cache.v_scale_pool, 1)
         return dequantize_kv(k, ks, dtype), dequantize_kv(v, vs, dtype)
     return k, v
 
 
 def apply_attention_decode_paged(p, cfg, x, cache: PagedKVCache, pos,
-                                 dtype, block_tables, use_kernel=False):
+                                 dtype, block_tables, use_kernel=False,
+                                 layer=None):
     """Single-token decode against a paged cache (see serve.kvcache).
+
+    ``layer``: this sublayer's index when ``cache`` holds a stacked group's
+    pools (the scan carry); None for an unstacked leaf.
 
     ``use_kernel``: attend through the tuned Pallas
     ``kernels.paged_decode_attention`` (block-table gather inside the
@@ -343,40 +388,29 @@ def apply_attention_decode_paged(p, cfg, x, cache: PagedKVCache, pos,
     assert block_tables is not None, \
         "paged caches need batch['block_tables'] in the decode batch"
     B = x.shape[0]
+    hd = cfg.head_dim
     q, k_new, v_new = _project_qkv(
         p, cfg, x, x, pos[:, None], pos[:, None], dtype)
     with jax.named_scope("kv_write"):
-        if cache.quantized:
-            # int8 pools: quantize the new token row and write value +
-            # scale pages through the same table entry (§Perf A4 at page
-            # granularity)
-            k8, ks = quantize_kv(k_new)
-            v8, vs = quantize_kv(v_new)
-            new_cache = PagedKVCache(
-                update_paged_cache(cache.k_pool, k8, pos, block_tables),
-                update_paged_cache(cache.v_pool, v8, pos, block_tables),
-                update_paged_cache(cache.k_scale_pool, ks, pos,
-                                   block_tables),
-                update_paged_cache(cache.v_scale_pool, vs, pos,
-                                   block_tables))
-        else:
-            new_cache = PagedKVCache(
-                update_paged_cache(cache.k_pool, k_new, pos, block_tables),
-                update_paged_cache(cache.v_pool, v_new, pos, block_tables))
+        new_cache = _write_paged(
+            cache, lambda pool, new: update_paged_cache(
+                pool, new, pos, block_tables, layer), k_new, v_new)
     if use_kernel:
         from repro.kernels import ops as KO   # lazy: keeps models jnp-only
+        kp = _kernel_pool(new_cache.k_pool, layer, hd)
+        vp = _kernel_pool(new_cache.v_pool, layer, hd)
         with jax.named_scope("attend"):
             if cache.quantized:
                 out = KO.paged_decode_attention_int8(  # dispatches via tune
-                    q[:, 0], new_cache.k_pool, new_cache.k_scale_pool,
-                    new_cache.v_pool, new_cache.v_scale_pool, block_tables,
-                    pos + 1)[:, None]
+                    q[:, 0], kp, _kernel_pool(new_cache.k_scale_pool, layer,
+                                              1),
+                    vp, _kernel_pool(new_cache.v_scale_pool, layer, 1),
+                    block_tables, pos + 1)[:, None]
             else:
                 out = KO.paged_decode_attention(       # dispatches via tune
-                    q[:, 0], new_cache.k_pool, new_cache.v_pool,
-                    block_tables, pos + 1)[:, None]
+                    q[:, 0], kp, vp, block_tables, pos + 1)[:, None]
     else:
-        k, v = gather_paged_kv(new_cache, block_tables, dtype)
+        k, v = gather_paged_kv(new_cache, block_tables, hd, dtype, layer)
         k, v = _tp_attend_kv(k, v, cfg)
         out = attend(q, k, v, causal=False, length=pos + 1, decode=True)
     return _out_proj(p, out.reshape(B, 1, -1), dtype), new_cache
@@ -386,7 +420,7 @@ def apply_attention_decode_paged(p, cfg, x, cache: PagedKVCache, pos,
 def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
                                 valid, stage_base, dtype, block_tables,
                                 stage: Optional[ChunkStage] = None,
-                                use_kernel=False):
+                                use_kernel=False, layer=None):
     """Chunked-prefill attention against a paged cache.
 
     ``x`` (B, C, d) is one fixed-size slab of prompt tokens starting at
@@ -394,7 +428,8 @@ def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
     rows are real; the slab's KV is written through the block table, then
     the slab attends causally over positions [0, offset + valid) — shared
     prefix pages included, so a prefix-cache hit starts mid-prompt with
-    ``offset`` > 0 and never recomputes the shared rows.
+    ``offset`` > 0 and never recomputes the shared rows.  ``layer`` as in
+    ``apply_attention_decode_paged``.
 
     ``stage`` (int8 pools only) keeps this request's own prefill rows in
     bf16 so later chunks do not re-read their predecessors through the
@@ -407,37 +442,25 @@ def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
     assert block_tables is not None, \
         "chunked prefill needs batch['block_tables']"
     B, C = x.shape[:2]
+    hd = cfg.head_dim
     positions = offset[:, None] + jnp.arange(C)[None, :]
     q, k_new, v_new = _project_qkv(p, cfg, x, x, positions, positions, dtype)
     with jax.named_scope("kv_write"):
-        if cache.quantized:
-            k8, ks = quantize_kv(k_new)
-            v8, vs = quantize_kv(v_new)
-            new_cache = PagedKVCache(
-                update_paged_cache_chunk(cache.k_pool, k8, offset, valid,
-                                         block_tables),
-                update_paged_cache_chunk(cache.v_pool, v8, offset, valid,
-                                         block_tables),
-                update_paged_cache_chunk(cache.k_scale_pool, ks, offset,
-                                         valid, block_tables),
-                update_paged_cache_chunk(cache.v_scale_pool, vs, offset,
-                                         valid, block_tables))
-        else:
-            new_cache = PagedKVCache(
-                update_paged_cache_chunk(cache.k_pool, k_new, offset, valid,
-                                         block_tables),
-                update_paged_cache_chunk(cache.v_pool, v_new, offset, valid,
-                                         block_tables))
+        new_cache = _write_paged(
+            cache, lambda pool, new: update_paged_cache_chunk(
+                pool, new, offset, valid, block_tables, layer),
+            k_new, v_new)
     length = offset + valid
     new_stage = None
     if use_kernel and not cache.quantized:
         from repro.kernels import ops as KO   # lazy: keeps models jnp-only
         with jax.named_scope("attend"):
             out = KO.prefill_attention_paged(
-                q, new_cache.k_pool, new_cache.v_pool, block_tables, offset,
-                length)
+                q, _kernel_pool(new_cache.k_pool, layer, hd),
+                _kernel_pool(new_cache.v_pool, layer, hd), block_tables,
+                offset, length)
     else:
-        k, v = gather_paged_kv(new_cache, block_tables, dtype)
+        k, v = gather_paged_kv(new_cache, block_tables, hd, dtype, layer)
         if stage is not None:
             # overlay this request's own bf16 rows (positions in
             # [stage_base, offset + valid)) on the dequantized view
@@ -466,11 +489,13 @@ def apply_attention_chunk_paged(p, cfg, x, cache: PagedKVCache, offset,
 
 @jax.named_scope("attention")
 def apply_attention_decode(p, cfg, x, cache, pos, dtype, block_tables=None,
-                           use_kernel=False):
-    """Single-token decode. ``pos``: (B,) current position; cache has fixed S."""
+                           use_kernel=False, layer=None):
+    """Single-token decode. ``pos``: (B,) current position; cache has fixed S.
+    ``layer`` indexes a paged cache's stacked pools (dense caches arrive
+    per layer and ignore it)."""
     if isinstance(cache, PagedKVCache):
         return apply_attention_decode_paged(p, cfg, x, cache, pos, dtype,
-                                            block_tables, use_kernel)
+                                            block_tables, use_kernel, layer)
     B = x.shape[0]
     q, k_new, v_new = _project_qkv(
         p, cfg, x, x, pos[:, None], pos[:, None], dtype)
@@ -523,12 +548,14 @@ def cross_kv(p, cfg, enc_out, dtype) -> KVCache:
 def init_paged_cache(cfg, spec: PageSpec, dtype) -> PagedKVCache:
     """Zeroed page pools for one attention sublayer (shared across slots).
 
+    Lane-dense pages, (P, page, KV*hd) (see ``PagedKVCache``).
     ``spec.kv_dtype == "int8"`` allocates int8 value pools plus bf16 scale
-    pages (same page indices — the allocator is oblivious to them)."""
+    pages, (P, page, KV) (same page indices — the allocator is oblivious
+    to them)."""
     KV, hd = cfg.num_kv_heads, cfg.head_dim
-    shape = (spec.num_pages, spec.page_size, KV, hd)
+    shape = (spec.num_pages, spec.page_size, KV * hd)
     if jnp.dtype(spec.kv_dtype) == jnp.dtype(jnp.int8):
-        sshape = shape[:-1] + (1,)
+        sshape = shape[:-1] + (KV,)
         return PagedKVCache(jnp.zeros(shape, jnp.int8),
                             jnp.zeros(shape, jnp.int8),
                             jnp.ones(sshape, jnp.bfloat16),

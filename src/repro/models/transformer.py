@@ -153,8 +153,12 @@ def _zero_state(cfg, mixer, B, dtype):
 
 def _apply_sublayer(p, cfg, rt, x, *, mixer, ffn, positions, state, dtype,
                     decode=False, pos=None, return_cache=False, enc_kv=None,
-                    pages=None, chunk=None):
+                    pages=None, chunk=None, layer=None):
     """Returns (x, new_state_or_cache, aux).
+
+    ``layer``: the index of this layer in a paged leaf's stacked pools
+    (``_run_groups`` hands paged leaves over whole); None when they are
+    unstacked.
 
     ``chunk`` ({offset, valid, stage_base} arrays) selects chunked-prefill
     mode: a slab of tokens is written through the paged cache's block table
@@ -178,7 +182,8 @@ def _apply_sublayer(p, cfg, rt, x, *, mixer, ffn, positions, state, dtype,
             p["mixer"], cfg, h, state["mixer"], chunk["offset"],
             chunk["valid"], chunk["stage_base"], dtype, block_tables=pages,
             stage=stage,
-            use_kernel=rt.paged_kernel_decode or M.kernel_routed())
+            use_kernel=rt.paged_kernel_decode or M.kernel_routed(),
+            layer=layer)
         out_state["mixer"] = c
         if stg is not None:
             out_state["stage"] = stg
@@ -206,7 +211,7 @@ def _apply_sublayer(p, cfg, rt, x, *, mixer, ffn, positions, state, dtype,
                     p["mixer"], cfg, h, state["mixer"], pos, dtype,
                     block_tables=pages,
                     use_kernel=rt.paged_kernel_decode or
-                    M.kernel_routed())
+                    M.kernel_routed(), layer=layer)
             out_state["mixer"] = c
         else:
             if cfg.attention == "mla":
@@ -271,35 +276,62 @@ def _apply_sublayer(p, cfg, rt, x, *, mixer, ffn, positions, state, dtype,
 
 def _apply_repeat(ps, cfg, rt, x, *, pattern, positions, states, dtype,
                   decode=False, pos=None, return_cache=False, enc_kv=None,
-                  pages=None, chunk=None):
+                  pages=None, chunk=None, layer=None):
     new_states, aux = [], jnp.zeros((), jnp.float32)
     for p, (mixer, ffn), st in zip(ps, pattern, states):
         x, ns, a = _apply_sublayer(
             p, cfg, rt, x, mixer=mixer, ffn=ffn, positions=positions,
             state=st, dtype=dtype, decode=decode, pos=pos,
             return_cache=return_cache, enc_kv=enc_kv, pages=pages,
-            chunk=chunk)
+            chunk=chunk, layer=layer)
         new_states.append(ns)
         aux = aux + a
     return x, new_states, aux
 
 
+def _split_paged(tree):
+    """(paged leaves, other leaves, join) of a state tree: ``join(paged,
+    other)`` rebuilds a tree of the same structure."""
+    leaves, tdef = jax.tree.flatten(tree, is_leaf=_is_paged)
+    is_pg = [_is_paged(v) for v in leaves]
+
+    def join(paged, other):
+        it_p, it_o = iter(paged), iter(other)
+        return tdef.unflatten([next(it_p) if pg else next(it_o)
+                               for pg in is_pg])
+
+    return ([v for v, pg in zip(leaves, is_pg) if pg],
+            [v for v, pg in zip(leaves, is_pg) if not pg], join)
+
+
+def _is_paged(x) -> bool:
+    return isinstance(x, A.PagedKVCache)
+
+
 def _run_groups(params_groups, groups, cfg, rt, x, *, positions, states,
                 dtype, decode=False, pos=None, return_cache=False,
                 enc_kv=None, pages=None, chunk=None):
-    """states: list (per group) of stacked per-repeat state lists."""
+    """states: list (per group) of stacked per-repeat state lists.
+
+    Paged KV leaves are shared page pools: a step changes one row per slot
+    in them.  In a stacked group they ride whole in the layer loop's carry
+    and each layer writes and reads them at its own layer index, so no
+    layer's pool is sliced out and written back (a whole-pool copy per
+    layer).  Per-slot state (dense KV, MLA, recurrent state, the chunk
+    stage) goes through the loop sliced per layer, as ``xs``/``ys``.
+    """
     out_states = []
     aux_total = jnp.zeros((), jnp.float32)
 
     for gi, g in enumerate(groups):
         ps, sts = params_groups[gi], states[gi]
 
-        def body(x, p_rep, st_rep):
+        def body(x, p_rep, st_rep, layer):
             return _apply_repeat(p_rep, cfg, rt, x, pattern=g.pattern,
                                  positions=positions, states=st_rep,
                                  dtype=dtype, decode=decode, pos=pos,
                                  return_cache=return_cache, enc_kv=enc_kv,
-                                 pages=pages, chunk=chunk)
+                                 pages=pages, chunk=chunk, layer=layer)
 
         if rt.remat == "dots":
             body = jax.checkpoint(
@@ -314,31 +346,40 @@ def _run_groups(params_groups, groups, cfg, rt, x, *, positions, states,
         elif rt.remat == "full":
             body = jax.checkpoint(body)
 
-        if g.repeats == 1 or not rt.scan_layers:
-            if g.repeats == 1:
-                x, ns, a = body(x, ps, sts)
-                out_states.append(ns)
-                aux_total = aux_total + a
-            else:
-                ns_list = []
-                for r in range(g.repeats):
-                    p_r = jax.tree.map(lambda v: v[r], ps)
-                    s_r = jax.tree.map(lambda v: v[r], sts)
-                    x, ns, a = body(x, p_r, s_r)
-                    ns_list.append(ns)
-                    aux_total = aux_total + a
-                out_states.append(jax.tree.map(
-                    lambda *vs: jnp.stack(vs), *ns_list))
-        else:
-            def scan_f(carry, xs):
-                x, aux = carry
-                p_rep, st_rep = xs
-                x, ns, a = body(x, p_rep, st_rep)
-                return (x, aux + a), ns
-
-            (x, aux_total), ns = jax.lax.scan(
-                scan_f, (x, aux_total), (ps, sts))
+        if g.repeats == 1:
+            x, ns, a = body(x, ps, sts, None)
             out_states.append(ns)
+            aux_total = aux_total + a
+            continue
+        # the loop's output may differ in structure from its input (a
+        # prefill returns caches for empty states): rebuild it with the
+        # join of the last layer's output
+        pools, rest, join = _split_paged(sts)
+        if not rt.scan_layers:
+            rest_list = []
+            for r in range(g.repeats):
+                p_r = jax.tree.map(lambda v: v[r], ps)
+                x, ns, a = body(x, p_r, join(pools, [v[r] for v in rest]), r)
+                pools, r_new, out_join = _split_paged(ns)
+                rest_list.append(r_new)
+                aux_total = aux_total + a
+            out_states.append(out_join(
+                pools, [jnp.stack(vs) for vs in zip(*rest_list)]))
+        else:
+            joins = []
+
+            def scan_f(carry, xs):
+                x, aux, pools = carry
+                p_rep, r_rep, layer = xs
+                x, ns, a = body(x, p_rep, join(pools, r_rep), layer)
+                pools, r_new, out_join = _split_paged(ns)
+                joins.append(out_join)
+                return (x, aux + a, pools), r_new
+
+            (x, aux_total, pools), rest = jax.lax.scan(
+                scan_f, (x, aux_total, pools),
+                (ps, rest, jnp.arange(g.repeats)))
+            out_states.append(joins[-1](pools, rest))
     return x, out_states, aux_total
 
 

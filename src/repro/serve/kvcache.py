@@ -31,6 +31,15 @@ The engine (``serve.scheduler``) talks only to the protocol; the model
 (``models.attention``) recognizes ``PagedKVCache`` leaves and routes decode
 reads/writes through the block table it receives in the step batch.
 
+Pools are lane-dense, ``(.., P, page, KV*hd)``: a page is whole (8|16, 128)
+tiles at head sizes 64 and 128, so the compiled step keeps a donated pool
+in the layout it arrives in.  A stacked layer group's pools ride whole in
+the layer scan's carry and each layer scatters into, and gathers from, its
+own index of the stack — a step moves O(tokens) pool bytes, with no
+per-layer slice, write-back or whole-pool copy (``models.transformer.
+_run_groups``).  Helpers here tell a stacked pool from a per-layer one by
+``PagedKVCache.stacked``.
+
 Kept import-light on purpose: no top-level ``repro.models`` import (models
 import this module for the ``PagedKVCache`` leaf type).
 """
@@ -52,18 +61,28 @@ NULL_PAGE = 0          # page 0 is never allocated: idle slots point here
 class PagedKVCache(NamedTuple):
     """Paged KV leaf: page pools, indexed by a per-slot block table.
 
-    ``k_pool``/``v_pool``: (P, page, KV, hd) — or (L, P, page, KV, hd) when
-    the layer group is stacked for ``lax.scan``.  The block table is *not*
-    part of the leaf: it is per-step input (``batch["block_tables"]``), while
-    the pools are per-step state — one table addresses every layer's pool.
+    ``k_pool``/``v_pool``: (P, page, KV*hd) — or (L, P, page, KV*hd) when
+    the layer group is stacked for ``lax.scan``.  A page row holds a
+    token's heads side by side (lane-dense): a page is a whole number of
+    (8|16, 128) tiles at head sizes 64 and 128, so XLA keeps the pool in
+    the layout the caller hands in and a one-row write updates it in place.
+    Rows are viewed as (KV, hd) only on the small new rows and on the
+    gathered view.  The block table is *not* part of the leaf: it is
+    per-step input (``batch["block_tables"]``), while the pools are
+    per-step state — one table addresses every layer's pool.
+
+    The model step carries a stacked leaf whole through its layer scan and
+    each layer scatters its rows into, and gathers its pages from, its
+    ``layer`` index of the stack: no layer's pool is ever sliced out,
+    copied, or written back (``models.transformer._run_groups``).
 
     ``kv_dtype="int8"`` pools carry *scale pages* alongside: per-(token,
-    head) absmax scales, (P, page, KV, 1), addressed by the SAME block
-    table — the allocator/free list never knows they exist.
+    head) absmax scales, (P, page, KV), addressed by the SAME block table
+    — the allocator/free list never knows they exist.
     """
     k_pool: jax.Array
     v_pool: jax.Array
-    k_scale_pool: Optional[jax.Array] = None   # (.., P, page, KV, 1) if int8
+    k_scale_pool: Optional[jax.Array] = None   # (.., P, page, KV) if int8
     v_scale_pool: Optional[jax.Array] = None
 
     @property
@@ -71,12 +90,17 @@ class PagedKVCache(NamedTuple):
         return self.k_scale_pool is not None
 
     @property
+    def stacked(self) -> bool:
+        """Whether the pools carry a leading layer axis (a scanned group)."""
+        return self.k_pool.ndim == 4
+
+    @property
     def page_size(self) -> int:
-        return self.k_pool.shape[-3]
+        return self.k_pool.shape[-2]
 
     @property
     def num_pages(self) -> int:
-        return self.k_pool.shape[-4]
+        return self.k_pool.shape[-3]
 
 
 class ChunkStage(NamedTuple):
@@ -385,16 +409,15 @@ def _is_paged(x) -> bool:
     return isinstance(x, PagedKVCache)
 
 
-def _pool_scatter(pool, rows, pages: List[int]):
+def _pool_scatter(pool, rows, pages: List[int], stacked: bool):
     """Write prefill KV rows into allocated pages of one pool leaf.
 
-    pool: (P, page, KV, hd) or (L, P, page, KV, hd) when the layer group is
-    stacked; rows: (T, KV, hd) / (L, T, KV, hd) correspondingly — padded or
-    truncated to exactly fill the pages.
+    pool: (P, page, W) or, ``stacked``, (L, P, page, W) — W is KV*hd for
+    value pools and KV for scale pools; rows: (T, KV, hd|1) / (L, T, KV,
+    hd|1) correspondingly — padded or truncated to exactly fill the pages.
     """
-    stacked = pool.ndim == 5
     t_axis = 1 if stacked else 0
-    page = pool.shape[t_axis + 1]
+    page = pool.shape[-2]
     need = len(pages) * page
     T = rows.shape[t_axis]
     if T < need:
@@ -403,8 +426,8 @@ def _pool_scatter(pool, rows, pages: List[int]):
         rows = jnp.pad(rows, pads)
     elif T > need:
         rows = jax.lax.slice_in_dim(rows, 0, need, axis=t_axis)
-    shp = (rows.shape[:t_axis] + (len(pages), page) + rows.shape[t_axis + 1:])
-    buf = rows.reshape(shp).astype(pool.dtype)
+    buf = rows.reshape(rows.shape[:t_axis] + (len(pages), page, -1))
+    buf = buf.astype(pool.dtype)
     idx = jnp.asarray(pages, jnp.int32)
     if stacked:
         return pool.at[:, idx].set(buf)
@@ -424,7 +447,7 @@ def copy_page(caches, src, dst):
         def cp(pool):
             if pool is None:
                 return None
-            if pool.ndim == 5:                  # stacked layer group
+            if leaf.stacked:
                 return pool.at[:, dst].set(pool[:, src])
             return pool.at[dst].set(pool[src])
 
@@ -754,7 +777,8 @@ class PagedBackend:
             if _is_paged(dst):
                 # src is the dense prefill KVCache for this sublayer;
                 # its batch axis is 0 (unstacked) or 1 (stacked layers)
-                b_axis = 0 if dst.k_pool.ndim == 4 else 1
+                st = dst.stacked
+                b_axis = 1 if st else 0
 
                 def rows(a):
                     return jax.lax.index_in_dim(a, row, axis=b_axis,
@@ -763,8 +787,8 @@ class PagedBackend:
                 use = pages[:n_prefill]
                 if not dst.quantized:
                     return PagedKVCache(
-                        _pool_scatter(dst.k_pool, rows(src.k), use),
-                        _pool_scatter(dst.v_pool, rows(src.v), use))
+                        _pool_scatter(dst.k_pool, rows(src.k), use, st),
+                        _pool_scatter(dst.v_pool, rows(src.v), use, st))
                 # int8 pools: scatter quantized rows + their scale rows.
                 # An int8 *prefill* cache (rt.kv_cache_dtype == "int8")
                 # already carries per-token scales — reuse them verbatim so
@@ -778,10 +802,10 @@ class PagedBackend:
                     k8, ks = quantize_kv(rows(src.k))
                     v8, vs = quantize_kv(rows(src.v))
                 return PagedKVCache(
-                    _pool_scatter(dst.k_pool, k8, use),
-                    _pool_scatter(dst.v_pool, v8, use),
-                    _pool_scatter(dst.k_scale_pool, ks, use),
-                    _pool_scatter(dst.v_scale_pool, vs, use))
+                    _pool_scatter(dst.k_pool, k8, use, st),
+                    _pool_scatter(dst.v_pool, v8, use, st),
+                    _pool_scatter(dst.k_scale_pool, ks, use, st),
+                    _pool_scatter(dst.v_scale_pool, vs, use, st))
             return dst
 
         # paged leaves first (is_leaf stops recursion there), then the

@@ -294,7 +294,8 @@ def test_paged_int8_allocator_accounting_no_leaked_pages():
     assert backend.spec.kv_dtype == "int8"
     leaf = eng.caches[0][0]["mixer"]
     assert leaf.quantized and leaf.k_pool.dtype == jnp.int8
-    assert leaf.k_scale_pool.shape == leaf.k_pool.shape[:-1] + (1,)
+    assert leaf.k_scale_pool.shape == leaf.k_pool.shape[:-1] + (
+        cfg.num_kv_heads,)
     # int8 pages obey the coarser 32-row granule (mechanism D)
     with pytest.raises(AssertionError, match="granule"):
         PagedBackend(page_size=16, kv_dtype="int8").init_caches(

@@ -5,7 +5,9 @@ of 64, d_ff 2816, vocab 151936; the engine's 8 slots x 1024 tokens in pages
 of 16) for one chip of a described ``v5e:2x2`` topology, at the kernel's
 default config, and asserts that the compiled program holds a Mosaic kernel
 (``tpu_custom_call``) rather than the interpreter's loop.  One more checks
-that exact-mode engine steps compile with XLA's excess precision off.
+that exact-mode engine steps compile with XLA's excess precision off, and
+two that the engine's decode and chunk steps update the paged KV pools in
+place: no copy, slice or write-back of a pool in the compiled program.
 
 The topology is described inside a module fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
@@ -126,3 +128,53 @@ def test_exact_mode_rounds_every_bf16_op(compile_for_tpu, one_chip, mode,
     txt = jax.jit(chain, compiler_options=compiler_options(mode)).lower(
         *args).compile().as_text()
     assert ("reduce-precision" in txt) == rounds
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_step_updates_kv_pools_in_place(compile_for_tpu, one_chip, step):
+    """The whole qwen1.5-0.5b decode and chunk steps over the engine's paged
+    pools hold no ``copy``, ``dynamic-slice`` or ``dynamic-update-slice``
+    whose result has a pool's (P, page) dims, per layer or stacked; the
+    donated pools alias the outputs; and the temporaries stay under the
+    pools' size.  Parameters are bf16, so no converted weight copy sits in
+    the temporaries and what remains is the step's own.  Fails the day
+    XLA reinserts a pool copy."""
+    import re
+
+    from repro.configs import get_config
+    from repro.models import RuntimeConfig, build_model
+    from repro.models import modules as M
+    from repro.serve.kvcache import PageSpec
+    from repro.serve.step import make_chunk_step, make_serve_step
+
+    model = build_model(get_config("qwen1.5-0.5b"),
+                        RuntimeConfig(remat="none"))
+    spec = PageSpec.for_engine(SLOTS, BLOCKS * PAGE, PAGE)
+
+    def shapes(tree, dtype=None):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, dtype or a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: M.unbox(model.init(jax.random.PRNGKey(0)))), bf16)
+    caches = shapes(jax.eval_shape(
+        lambda: model.init_caches(SLOTS, BLOCKS * PAGE, page_spec=spec)))
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(caches))
+    rows, fn = ((SLOTS, 1), make_serve_step(model)) if step == "decode" \
+        else ((1, CHUNK), make_chunk_step(model))
+    batch = {"tokens": rows, "block_tables": (rows[0], BLOCKS)}
+    batch.update({"pos": (SLOTS,)} if step == "decode" else
+                 {k: (1,) for k in ("offset", "valid", "stage_base")})
+    batch = {k: jax.ShapeDtypeStruct(v, i32, sharding=one_chip)
+             for k, v in batch.items()}
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+        params, batch, caches).compile()
+    pool = re.compile(r"= \w+\[[\d,]*\b%d,%d," % (spec.num_pages, PAGE))
+    moves = re.compile(r"\b(copy|dynamic-slice|dynamic-update-slice)\(")
+    bad = [ln.strip() for ln in compiled.as_text().splitlines()
+           if pool.search(ln) and moves.search(ln)]
+    assert not bad, "\n".join(ln[:160] for ln in bad[:4])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
