@@ -1,4 +1,6 @@
-"""What one decode step *needs*: FLOPs and HBM bytes from shapes alone.
+"""What one decode step of the dense decoder *needs*: FLOPs and HBM bytes
+from shapes alone.  ``reference/dense_decoder.py`` gives ``decode_step`` to
+the harness; another architecture's module counts its own step.
 
 Counted from the configuration's ``model`` block and the requests in the
 decode batch, at the configuration's compute dtype, as the least that any

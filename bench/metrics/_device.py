@@ -3,7 +3,8 @@
 
 def program(run, name):
     """(executions, device seconds) of a program in the traced slice, or
-    None where the trace has none."""
+    None where the trace has none.  The seconds are the mean over the
+    cell's chips (``trace_reduce.py``), so a step's time is one chip's."""
     trace = run.get("trace")
     if not trace:
         return None
@@ -15,11 +16,12 @@ def program(run, name):
 
 def decode_need_s(run):
     """(least seconds per decode step by compute, by bytes), averaged over
-    the decode steps submitted in the traced slice, from ``counts.py`` and
-    the chip's peaks."""
+    the decode steps submitted in the traced slice: the whole step's need
+    (the architecture's ``decode_step`` count) over the peaks of all the
+    cell's chips, which share the step."""
     need, peaks = run.get("need"), run.get("peaks")
     if not need or not need["steps"] or not peaks:
         return None
-    n = need["steps"]
-    return (need["flops"] / n / peaks["bf16_flops_per_s"],
-            need["bytes"] / n / peaks["hbm_bytes_per_s"])
+    per_step = run["chips"] * need["steps"]
+    return (need["flops"] / per_step / peaks["bf16_flops_per_s"],
+            need["bytes"] / per_step / peaks["hbm_bytes_per_s"])

@@ -1,6 +1,6 @@
-"""Whole decode step: FLOPs a decode step needs (``counts.py``) over the
-mean device time of a step times the chip's peak bf16 FLOP/s, in
-percent."""
+"""Whole decode step: FLOPs a decode step needs (the architecture's count)
+over the mean device time of a step times the peak bf16 FLOP/s of all the
+cell's chips, in percent."""
 from _device import decode_need_s, program
 
 

@@ -1,8 +1,9 @@
 """Kernels (the whole decode program until the program names its scopes):
 the decode step's share of its roofline, in percent.  The least time a
 step needs, the larger of its FLOPs over peak bf16 FLOP/s and its bytes
-over peak HBM bytes/s (``counts.py``: weights once at the compute dtype,
-each live KV row once), over the mean device time of a decode step."""
+over peak HBM bytes/s of all the cell's chips (the architecture's count:
+weights once at the compute dtype, each live KV row once), over the mean
+device time of a decode step on a chip."""
 from _device import decode_need_s, program
 
 

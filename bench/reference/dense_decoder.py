@@ -1,4 +1,24 @@
-"""Plain reference of the dense decoder both configurations run.
+"""Plain reference of the dense decoder, and everything else about that
+architecture that the harness needs.
+
+A configuration file names its architecture's module (``"reference":
+"dense_decoder"``); ``run.py`` reads it by that name and names none
+itself.  A module provides:
+
+* ``shapes(m)`` and ``make(m, seed, layout=None, shardings=None)``: every
+  weight's shape and kind, and the seeded maker (``weights.draw``);
+* ``program_tree(w)``: the flat weights laid out as the program takes
+  them;
+* ``frozen(m)`` and ``logits(w, frozen, tokens, rows, mode)``: the plain
+  reference and, with ``mode="fp8"``, its control;
+* ``decode_step(m, batch, dtype)``: the (FLOPs, bytes) a decode step needs;
+* ``program_fields(cfg)``: the program's values of the file's ``model``
+  keys that the program has, compared before a run;
+* ``TINY``: a configuration at toy widths (registry id, overrides,
+  ``model`` block, engine fields) for the contract test on the CPU.
+
+The dense decoder's weights and counts live in ``weights.py`` and
+``counts.py``; this module gives them to the harness.
 
 Straightforward ``jax.numpy`` in float32 at ``Precision.HIGHEST``: no
 kernels, no cache, no batching.  One sequence at a time, layer by layer,
@@ -32,6 +52,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from counts import decode_step  # noqa: F401  (the module's contract)
+from weights import make, program_tree, shapes  # noqa: F401
 
 HIGHEST = lax.Precision.HIGHEST
 Q_BLOCK = 512
@@ -155,3 +178,32 @@ class Frozen(dict):
 def frozen(m: dict) -> Frozen:
     return Frozen((k, v) for k, v in m.items()
                   if isinstance(v, (int, float, bool, str)))
+
+
+def program_fields(cfg) -> dict:
+    """The registry configuration's value of each ``model`` key."""
+    return {"d_model": cfg.d_model, "n_layers": cfg.num_layers,
+            "n_heads": cfg.num_heads, "n_kv_heads": cfg.num_kv_heads,
+            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
+            "vocab": cfg.vocab_size, "qkv_bias": cfg.qkv_bias,
+            "tie_embeddings": cfg.tie_embeddings,
+            "rope_theta": cfg.rope_theta, "norm_eps": cfg.norm_eps,
+            "compute_dtype": cfg.dtype}
+
+
+# qwen1.5-0.5b cut to toy widths: GQA (4 query heads over 2 KV heads, as
+# GLM-4's 32 over 2 at tp=4), QKV bias, an untied head
+TINY = {
+    "registry": "qwen1.5-0.5b",
+    "registry_overrides": {"num_layers": 2, "d_model": 64, "num_heads": 4,
+                           "num_kv_heads": 2, "head_dim": 16, "d_ff": 128,
+                           "vocab_size": 512},
+    "model": {"d_model": 64, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
+              "head_dim": 16, "d_ff": 128, "vocab": 512, "qkv_bias": True,
+              "tie_embeddings": False, "rope_theta": 1000000.0,
+              "rope_dims": 16, "norm_eps": 1e-06,
+              "compute_dtype": "bfloat16"},
+    "engine": {"slots": 4, "cache_len": 128, "backend": "paged",
+               "page_size": 16, "chunked_prefill": True, "chunk_size": 32,
+               "prefix_cache": True},
+}

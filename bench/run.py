@@ -6,29 +6,42 @@
 The cell, its configuration and its traffic mix are found by name in
 ``BENCHMARK.json``: ``bench/configs/<config>.json``,
 ``bench/traffic/<traffic>.json`` and ``bench/limits/<cell>.json``.  Per-layer
-metrics are read by ``bench/metrics/<metric>.py``.  Nothing here names a
-cell, a configuration or a mix.
+metrics are read by ``bench/metrics/<metric>.py``.  Everything about an
+architecture (its weights' shapes and maker, the program's parameter
+layout, the plain reference, the count of a decode step's work, the fields
+compared with the program) comes from ``bench/reference/<name>.py``, the
+module the configuration file names under ``"reference"``; see
+``reference/dense_decoder.py`` for the contract.  Nothing here names a
+cell, a configuration, a mix or an architecture.
+
+A cell's chips are its engine's chips: the mix's engine ``tp`` (1 when
+absent) has to equal the cell's ``chips``, or the run exits with no result.
 
 A run, in one process (the process that touches JAX holds the chip):
 
-1. set-up: weights made on the device from ``--seed`` (``weights.py``), the
-   engine built by ``repro.serve.build_engine`` from the configuration's
-   registry id and the mix's engine fields, its programs warmed (a chunk, a
-   decode step, a copy-on-write page copy), then the lead-in the traffic
-   needs.  ``setup_s`` runs from process start to the window's opening.
+1. set-up: weights made from ``--seed`` in one jitted call, on the chips
+   that serve them: on one chip, or sharded as the engine's own
+   tensor-parallel plan shards them (so the engine moves none); the engine
+   built by ``repro.serve.build_engine`` from the configuration's registry
+   id and the mix's engine fields, its programs warmed (a chunk, a decode
+   step, a copy-on-write page copy), then the lead-in the traffic needs.
+   ``setup_s`` runs from process start to the window's opening.
 2. the window: ``--seconds`` of traffic from ``traffic/generate.py``,
    submitted when due (open loop) or when a request finishes (closed
    loop), the engine stepped in between.  Token times are what the host
    sees after each ``engine.step()``; every latency runs from due time.
 3. with ``--trace 1``, a profiler trace of a slice of the window, reduced by
-   ``trace_reduce.py``; the line then carries the per-layer metrics.
+   ``trace_reduce.py`` (device time is the mean over the cell's chips);
+   the line then carries the per-layer metrics.  A roofline share or an
+   MFU sets a step's need against the peaks of all the cell's chips.
 4. the check, after the window: a seeded sample of the requests the window
    served (finished in it or still decoding at its close; the one with the
-   most tokens among them), run through the plain reference
-   (``reference/``) on weights made again from the seed, once the engine is
-   freed.  ``correct`` holds when every served token lies within the cell's
-   limit of the reference's best logit, and every finished request has all
-   its tokens.  ``--control 1`` puts the fp8 control (the reference one
+   most tokens among them), run through the plain reference on weights
+   made again from the seed, each sharded over the cell's chips on its
+   largest axis that they divide, once the engine is freed.  ``correct``
+   holds when every served token lies within the cell's limit of the
+   reference's best logit, and every finished request has all its
+   tokens.  ``--control 1`` puts the fp8 control (the reference one
    precision step down) in the program's place: its tokens are the ones
    judged, and the run has to come out not correct.
 
@@ -108,6 +121,19 @@ def metric_reader(name: str):
     return mod.read
 
 
+def reference(config: dict):
+    """The architecture's module, ``bench/reference/<name>.py``, by the
+    name the configuration file gives; a name with no module ends the run
+    without a result."""
+    name = str(config.get("reference", ""))
+    path = BENCH / "reference" / f"{name}.py"
+    if not name.isidentifier() or not path.is_file():
+        raise SystemExit(f"bench: the configuration names the reference "
+                         f"{name!r}, and bench/reference/ has no such "
+                         f"module")
+    return importlib.import_module(f"reference.{name}")
+
+
 def read_per_layer(per_layer, run_info) -> dict:
     """Every per-layer metric of the cell, read from the run's records.
     One whose source is missing from the run (say, a jitted program that
@@ -140,32 +166,28 @@ def devices_or_exit(platform: str, chips: int):
 
 
 def device_record(devs) -> dict:
-    peak = 0
-    for d in devs:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    """The devices as JAX reports them; ``memory_peak_bytes`` is the
+    fullest chip's peak, ``memory_peak_bytes_per_chip`` each chip's."""
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devs]
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs), "memory_peak_bytes": peak}
+            "count": len(devs), "memory_peak_bytes": max(peaks),
+            "memory_peak_bytes_per_chip": peaks}
 
 
 # ----------------------------------------------------------------- engine
-def model_config(config: dict):
+def model_config(config: dict, R):
     """The program's registry configuration, with the file's overrides,
-    checked against the file's ``model`` block."""
+    checked against the file's ``model`` block on the fields that the
+    architecture's module ``R`` names."""
     import dataclasses
 
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(config["registry"]),
                               **config.get("registry_overrides", {}))
     m = config["model"]
-    have = {"d_model": cfg.d_model, "n_layers": cfg.num_layers,
-            "n_heads": cfg.num_heads, "n_kv_heads": cfg.num_kv_heads,
-            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
-            "vocab": cfg.vocab_size, "qkv_bias": cfg.qkv_bias,
-            "tie_embeddings": cfg.tie_embeddings,
-            "rope_theta": cfg.rope_theta, "norm_eps": cfg.norm_eps,
-            "compute_dtype": cfg.dtype}
-    differ = {k: (v, m[k]) for k, v in have.items() if m[k] != v}
+    differ = {k: (v, m.get(k)) for k, v in R.program_fields(cfg).items()
+              if m.get(k) != v}
     if differ:
         raise SystemExit(f"bench: the program's {config['registry']!r} "
                          f"differs from the configuration file: {differ}")
@@ -186,28 +208,66 @@ def engine_config(fields: dict):
     return EngineConfig(**{k: v for k, v in fields.items() if k in known})
 
 
-def build(spec: dict, seed: int):
+def engine_shardings(model, ecfg):
+    """Where the engine keeps its weights: None (the default device) for
+    ``tp`` 1, else, given the abstract parameter tree, the sharding of
+    each leaf under the engine's own plan (``repro.dist.tp.plan``'s mesh
+    and ``param_specs``), which ``shard_params`` then leaves in place."""
+    if ecfg.tp == 1:
+        return None
+    import jax
+    from jax.sharding import NamedSharding
+
+    from repro.dist import tp as TP
+    plan = TP.plan(model, ecfg.tp, ecfg.tp_mode)
+
+    def place(params):
+        specs = TP.param_specs(model, params, plan)
+        return jax.tree.map(lambda _, s: NamedSharding(plan.mesh, s),
+                            params, specs)
+    return place
+
+
+def spread_over(devs):
+    """Where the check keeps its weights: None (the default device) on one
+    chip, else each leaf sharded over ``devs`` on its largest axis that
+    they divide (replicated where none does)."""
+    if len(devs) == 1:
+        return None
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.asarray(devs), ("chips",))
+    n = len(devs)
+
+    def one(leaf):
+        axes = [i for i in range(leaf.ndim) if leaf.shape[i] % n == 0]
+        spec = [None] * leaf.ndim
+        if axes:
+            spec[max(axes, key=lambda i: leaf.shape[i])] = "chips"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+    return lambda w: jax.tree.map(one, w)
+
+
+def build(spec: dict, seed: int, R):
     import jax
 
-    import weights as W
     from repro.models import RuntimeConfig, build_model
     from repro.models import modules as M
     from repro.serve import build_engine
-    cfg = model_config(spec["config"])
+    cfg = model_config(spec["config"], R)
     ecfg = engine_config(spec["traffic"]["engine"])
-    w = W.make(spec["config"]["model"], seed)
-    params = W.program_tree(w)
-    jax.block_until_ready(params)
-    log(f"set-up: weights made at {time.perf_counter() - T_START:.3f}s")
+    m = spec["config"]["model"]
     model = build_model(cfg, RuntimeConfig(remat="none"))
     want = jax.eval_shape(lambda: M.unbox(model.init(jax.random.PRNGKey(0))))
-    got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                       params)
+    got = jax.eval_shape(lambda: R.make(m, seed, layout=R.program_tree))
     if jax.tree.structure(want) != jax.tree.structure(got) or \
             jax.tree.leaves(want) != jax.tree.leaves(got):
-        raise SystemExit("bench: the program's parameter layout differs "
-                         "from weights.program_tree")
+        raise SystemExit(f"bench: the program's parameter layout differs "
+                         f"from {R.__name__}.program_tree")
+    params = R.make(m, seed, layout=R.program_tree,
+                    shardings=engine_shardings(model, ecfg))
     jax.block_until_ready(params)
+    log(f"set-up: weights made at {time.perf_counter() - T_START:.3f}s")
     return build_engine(cfg, ecfg, params=params)
 
 
@@ -405,12 +465,13 @@ def end_to_end(driver, ws, we, setup_s, diag):
 
 
 class DecodeNeed:
-    """Sums ``counts.decode_step`` over the decode steps submitted while
-    the trace runs (after each ``engine.step()``, the requests that have a
-    first token and are not finished are the ones its decode step feeds)."""
+    """Sums the architecture's ``decode_step`` count over the decode steps
+    submitted while the trace runs (after each ``engine.step()``, the
+    requests that have a first token and are not finished are the ones its
+    decode step feeds)."""
 
-    def __init__(self, m, dtype):
-        self.m, self.dtype = m, dtype
+    def __init__(self, R, m):
+        self.R, self.m = R, m
         self.on = False
         self.flops = self.bytes = 0.0
         self.steps = 0
@@ -418,34 +479,32 @@ class DecodeNeed:
     def __call__(self, driver, now):
         if not self.on:
             return
-        import counts
         batch = [(tr.spec.prompt.size + tr.seen, tr.spec.head_id,
                   tr.spec.head_len)
                  for tr in driver.live.values() if tr.seen >= 1]
         if not batch:
             return
-        f, b = counts.decode_step(self.m, batch, self.dtype)
+        f, b = self.R.decode_step(self.m, batch, self.m["compute_dtype"])
         self.flops += f
         self.bytes += b
         self.steps += 1
 
 
 # ------------------------------------------------------------------ check
-def check(spec, seed, sample, control: bool):
-    """Run the reference over the sampled requests: the largest gap by
-    which a served token's reference logit lies below the reference's best.
-    With ``control``, the fp8 control stands in the program's place at the
-    same positions, and the gap of its own top token is returned as well."""
+def check(spec, seed, sample, R, devs, control: bool):
+    """Run the reference ``R`` over the sampled requests, its weights
+    spread over the cell's chips ``devs``: the largest gap by which a
+    served token's reference logit lies below the reference's best.  With
+    ``control``, the fp8 control stands in the program's place at the same
+    positions, and the gap of its own top token is returned as well."""
     import jax
     import jax.numpy as jnp
 
-    import weights as W
-    from reference import dense_decoder as R
     m = spec["config"]["model"]
     frozen = R.frozen(m)
     T = int(spec["traffic"]["engine"]["cache_len"])
     n_rows = int(spec["traffic"]["output"]["max"])
-    w = W.make(m, seed)
+    w = R.make(m, seed, shardings=spread_over(devs))
     gap = ctl = 0.0
     served = 0
     for req in sample:
@@ -515,12 +574,17 @@ def run(args, spec, hooks=None, diag=None) -> dict:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     cell, traffic = spec["cell"], spec["traffic"]
+    tp = int(traffic["engine"].get("tp", 1))
+    if tp != int(cell["chips"]):
+        raise SystemExit(f"bench: the cell asks for {cell['chips']} chips "
+                         f"and its engine for tp={tp}; they have to agree")
+    R = reference(spec["config"])
     devs = devices_or_exit(args.platform, int(cell["chips"]))
     on_chip = args.platform == "tpu"
     tracing = bool(args.trace) and on_chip
     log(f"set-up: devices at {time.perf_counter() - T_START:.3f}s")
 
-    engine = build(spec, args.seed)
+    engine = build(spec, args.seed, R)
     if hooks and "engine" in hooks:
         hooks["engine"](engine)
     log(f"set-up: weights and engine at {time.perf_counter() - T_START:.3f}s")
@@ -535,8 +599,7 @@ def run(args, spec, hooks=None, diag=None) -> dict:
             compiles[0] += 1
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    m = spec["config"]["model"]
-    need = DecodeNeed(m, m["compute_dtype"]) if tracing else None
+    need = DecodeNeed(R, spec["config"]["model"]) if tracing else None
     specs = generate(traffic, args.seed,
                      request_count(traffic, args.seconds),
                      engine.model.cfg.vocab_size)
@@ -620,6 +683,7 @@ def run(args, spec, hooks=None, diag=None) -> dict:
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
         record["peaks"] = peaks(device["kind"])
+        record["chips"] = len(devs)
         record["need"] = {"flops": need.flops, "bytes": need.bytes,
                           "steps": need.steps}
 
@@ -638,7 +702,8 @@ def run(args, spec, hooks=None, diag=None) -> dict:
     gc.collect()
 
     t_check = time.perf_counter()
-    gap, ctl, served = check(spec, args.seed, sample, bool(args.control)) \
+    gap, ctl, served = check(spec, args.seed, sample, R, devs,
+                             bool(args.control)) \
         if sample else (float("inf"), 0.0, 0)
     log(f"check took {time.perf_counter() - t_check:.3f}s, "
         f"{time.perf_counter() - we:.3f}s after the window's close")

@@ -115,3 +115,17 @@ def test_metrics():
     perf = (ROOT / "PERF.md").read_text()
     for layer in layers:
         assert layer in perf
+
+
+def test_each_configuration_names_its_module_and_each_cell_its_chips():
+    """A configuration's architecture module is there, and a cell's chips
+    are its engine's tensor-parallel degree (1 when the mix gives none)."""
+    b = bench()
+    for c in b["configs"]:
+        name = json.loads((ROOT / c["file"]).read_text())["reference"]
+        assert NAME.match(name)
+        assert (BENCH / "reference" / f"{name}.py").is_file(), name
+    for c in b["workloads"]:
+        mix = json.loads((BENCH / "traffic" / f"{c['traffic']}.json")
+                         .read_text())
+        assert mix["engine"].get("tp", 1) == c["chips"], c["name"]

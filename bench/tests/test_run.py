@@ -73,7 +73,8 @@ def test_a_metric_whose_source_is_missing_ends_the_run():
     red = TR.reduce_trace(BENCH / "tests" / "data" / "small.xplane.pb",
                           table={"jit_renamed_step": "decode"})
     info = {"trace": red, "need": {"flops": 1.0, "bytes": 1.0, "steps": 1},
-            "peaks": {"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0}}
+            "peaks": {"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0},
+            "chips": 1}
     defs = [{"name": "decode_step_ms", "unit": "ms"}]
     with pytest.raises(SystemExit, match="decode_step_ms"):
         run.read_per_layer(defs, info)

@@ -5,15 +5,11 @@ from __future__ import annotations
 import argparse
 import copy
 
-MODEL = {"d_model": 64, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
-         "head_dim": 16, "d_ff": 128, "vocab": 512, "qkv_bias": True,
-         "tie_embeddings": False, "rope_theta": 1000000.0, "rope_dims": 16,
-         "norm_eps": 1e-06, "compute_dtype": "bfloat16"}
-OVERRIDES = {"num_layers": 2, "d_model": 64, "num_heads": 4,
-             "num_kv_heads": 2, "head_dim": 16, "d_ff": 128,
-             "vocab_size": 512}
-ENGINE = {"slots": 4, "cache_len": 128, "backend": "paged", "page_size": 16,
-          "chunked_prefill": True, "chunk_size": 32, "prefix_cache": True}
+from reference.dense_decoder import TINY
+
+MODEL = TINY["model"]
+OVERRIDES = TINY["registry_overrides"]
+ENGINE = TINY["engine"]
 
 OPEN = {"loop": "open", "rate_per_s": 12.0, "lead_in_s": 0.5,
         "shape_seed": 5,
@@ -27,13 +23,20 @@ CLOSED = {"loop": "closed", "concurrency": 4, "shape_seed": 6,
           "engine": ENGINE, "check_requests": 6}
 
 
-def spec(traffic=OPEN, limit=0.05):
+def spec(traffic=OPEN, limit=0.05, chips=1):
+    """The toy cell; on ``chips`` > 1 its engine is tensor-parallel over
+    them (``tp`` = chips, exact mode)."""
+    traffic = copy.deepcopy(traffic)
+    if chips > 1:
+        traffic["engine"] = dict(traffic["engine"], tp=chips,
+                                 tp_mode="exact")
     return {"cell": {"name": "tiny", "config": "tiny", "traffic": "tiny",
-                     "chips": 1},
-            "config": {"registry": "qwen1.5-0.5b",
+                     "chips": chips},
+            "config": {"registry": TINY["registry"],
                        "registry_overrides": dict(OVERRIDES),
+                       "reference": "dense_decoder",
                        "model": dict(MODEL)},
-            "traffic": copy.deepcopy(traffic),
+            "traffic": traffic,
             "limits": {"max_logit_gap": limit},
             "end_to_end": [{"name": n} for n in
                            ("setup_s", "ttft_p50_s", "ttft_p95_s", "itl_p50_s",

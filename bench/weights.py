@@ -1,4 +1,4 @@
-"""Seeded random weights for a configuration, made on the device.
+"""Seeded random weights of the dense decoder, made on the device.
 
 The benchmark makes the weights itself, in one jitted call from the run's
 seed, as float32 (the type the program serves from: it holds float32
@@ -6,9 +6,19 @@ masters and computes in the configuration's compute dtype).  The same call
 gives the plain reference the same weights later in the run, so the
 reference takes nothing that the program made.
 
-``program_tree`` lays the weights out as the program's dense decoder takes
-them (``repro.models`` parameters of one layer group stacked over layers);
-``run.py`` checks that layout against the program's own before serving.
+What belongs to one architecture is read through its reference module,
+``reference/<name>.py``, which the configuration file names
+(``"reference"``).  This file holds the dense decoder's part, which
+``reference/dense_decoder.py`` takes from here: ``shapes`` (every weight's
+shape and kind) and ``program_tree`` (the weights laid out as the
+program's dense decoder takes them, ``repro.models`` parameters of one
+layer group stacked over layers; ``run.py`` checks that layout against the
+program's own before serving).  ``draw`` is for every architecture: it
+makes any ``shapes`` table in one jitted call, laid out and placed as the
+caller asks, so that a weight is made on the chips that hold it.  With
+``jax_threefry_partitionable`` on (JAX's default), the values do not
+depend on the placement: a sharded call gives, bit for bit, what one
+device gives.
 
 Scales keep activations of order one through depth: matrices are normal
 with variance 1/fan_in, biases 0.1-normal, norm scales 1 + 0.1-normal and
@@ -69,14 +79,26 @@ def _draw(key, shape, kind):
     return x                                    # embedding rows
 
 
-def make(m: dict, seed: int) -> dict:
-    """All weights of ``m`` for ``seed``, as a flat name -> array dict."""
-    spec = shapes(m)
-
+def draw(spec: dict, seed: int, layout=None, shardings=None):
+    """Every weight of ``spec`` (name -> (shape, kind), as ``shapes``
+    gives it) for ``seed``, in one jitted call: a flat name -> array dict,
+    or ``layout`` of it.  ``shardings``, given the abstract result, returns
+    the call's ``out_shardings``: each device then makes only its own
+    shards.  Without it the weights are made on the default device."""
     def gen(key):
-        return {name: _draw(jax.random.fold_in(key, i), shape, kind)
-                for i, (name, (shape, kind)) in enumerate(spec.items())}
-    return jax.jit(gen)(seed_key(seed))
+        w = {name: _draw(jax.random.fold_in(key, i), shape, kind)
+             for i, (name, (shape, kind)) in enumerate(spec.items())}
+        return w if layout is None else layout(w)
+    key = seed_key(seed)
+    placed = {} if shardings is None else {
+        "out_shardings": shardings(jax.eval_shape(gen, key))}
+    return jax.jit(gen, **placed)(key)
+
+
+def make(m: dict, seed: int, layout=None, shardings=None):
+    """All weights of the dense decoder ``m`` for ``seed`` (see
+    ``draw``)."""
+    return draw(shapes(m), seed, layout, shardings)
 
 
 def program_tree(w: dict) -> dict:
